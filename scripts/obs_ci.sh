@@ -45,20 +45,22 @@ assert seq["chaos.inject"] < seq["engine.degraded_enter"] \
 print("black box ordered:", seq)
 EOF
 
-echo "== perf gate: green on the committed r05 receipt =="
-python tools/perfgate.py --receipt BENCH_r05.json
+echo "== perf gate: green on its synthetic r05 receipt =="
+python tools/perfgate.py --receipt tests/data/perfgate/BENCH_r05.json \
+    --repo tests/data/perfgate
 
 echo "== perf gate: RED on a -20% degraded receipt =="
 python - <<'EOF'
 import json, os, subprocess, sys, tempfile
-d = json.load(open("BENCH_r05.json"))["parsed"]
+d = json.load(open("tests/data/perfgate/BENCH_r05.json"))["parsed"]
 for k in ("value", "client_ops_s", "sustained_ops_s", "sus_mixed_ops_s"):
     if d.get(k):
         d[k] = round(d[k] * 0.8)
 p = os.path.join(tempfile.mkdtemp(prefix="perfgate_ci_"), "degraded.json")
 json.dump(d, open(p, "w"))
 rc = subprocess.run([sys.executable, "tools/perfgate.py",
-                     "--receipt", p]).returncode
+                     "--receipt", p, "--repo",
+                     "tests/data/perfgate"]).returncode
 assert rc == 1, f"perfgate must flag a -20% receipt (rc={rc})"
 print("degraded receipt flagged (rc=1)")
 EOF
@@ -76,12 +78,13 @@ KEYS=20000 B=8192 DEVB=8192 K=2 STEPS=6 FUSION=pipelined \
 echo "== device plane: synthetic-retrace pin is RED =="
 python - <<'EOF'
 import json, os, subprocess, sys, tempfile
-d = json.load(open("BENCH_r05.json"))["parsed"]
+d = json.load(open("tests/data/perfgate/BENCH_r05.json"))["parsed"]
 d["device"] = {"ledger": {"retraces": 1}}
 p = os.path.join(tempfile.mkdtemp(prefix="perfgate_ci_"), "retrace.json")
 json.dump(d, open(p, "w"))
 rc = subprocess.run([sys.executable, "tools/perfgate.py",
-                     "--receipt", p]).returncode
+                     "--receipt", p, "--repo",
+                     "tests/data/perfgate"]).returncode
 assert rc == 1, f"perfgate must flag a steady-state retrace (rc={rc})"
 print("retraced receipt flagged (rc=1)")
 EOF

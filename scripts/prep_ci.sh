@@ -42,7 +42,7 @@ EOF
 echo "== perfgate: live receipt with default request-plane stamps stays green =="
 python - <<'EOF'
 import json, os, subprocess, sys, tempfile
-d = json.load(open("BENCH_r05.json"))["parsed"]
+d = json.load(open("tests/data/perfgate/BENCH_r05.json"))["parsed"]
 cfg = dict(d.get("config") or {})
 tmp = tempfile.mkdtemp(prefix="prep_ci_")
 
@@ -53,7 +53,8 @@ d["config"] = dict(cfg, prep_impl="host", write_combine=False)
 p = os.path.join(tmp, "stamped.json")
 json.dump(d, open(p, "w"))
 rc = subprocess.run([sys.executable, "tools/perfgate.py",
-                     "--receipt", p]).returncode
+                     "--receipt", p, "--repo",
+                     "tests/data/perfgate"]).returncode
 assert rc == 0, f"default-stamp receipt must stay green (rc={rc})"
 
 # device placement is incomparable config: the wall must hold on the
@@ -62,7 +63,8 @@ d["config"] = dict(cfg, prep_impl="device", write_combine=False)
 p = os.path.join(tmp, "device.json")
 json.dump(d, open(p, "w"))
 rc = subprocess.run([sys.executable, "tools/perfgate.py",
-                     "--receipt", p]).returncode
+                     "--receipt", p, "--repo",
+                     "tests/data/perfgate"]).returncode
 assert rc == 2, f"device-placement receipt must be incomparable (rc={rc})"
 print("perfgate: default stamps green, device placement walled")
 EOF

@@ -20,24 +20,7 @@ RDMA; reference at /root/reference) designed TPU-first:
 See SURVEY.md for the full reference analysis this build follows.
 """
 
-import jax as _jax
-
-if not hasattr(_jax, "shard_map"):
-    # Compatibility shim for JAX < 0.6: the public ``jax.shard_map``
-    # entry point (keyword-only, ``check_vma=``) is the experimental
-    # ``shard_map`` (``check_rep=``).  Installed once at package import
-    # so every call site can use the current public spelling.
-    from jax.experimental.shard_map import shard_map as _shard_map
-
-    def _compat_shard_map(f, *, mesh, in_specs, out_specs,
-                          check_vma: bool = True, **kwargs):
-        return _shard_map(f, mesh=mesh, in_specs=in_specs,
-                          out_specs=out_specs, check_rep=check_vma,
-                          **kwargs)
-
-    _jax.shard_map = _compat_shard_map
-
-from sherman_tpu.config import DSMConfig, TreeConfig  # noqa: E402
+from sherman_tpu.config import DSMConfig, TreeConfig
 
 __version__ = "0.1.0"
 

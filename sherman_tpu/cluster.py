@@ -195,3 +195,40 @@ class Cluster:
     def broadcast_new_root(self, addr: int, level: int) -> None:
         for d in self.directories:
             d.new_root(addr, level)
+
+
+# ---------------------------------------------------------------------------
+# The one sizing + construction rule of a bulk-loaded deployment: bench.py,
+# chip_smoke.py and the tools/ drivers (through tools/common) all call it.
+# ---------------------------------------------------------------------------
+
+def pages_for_keys(n_keys: int, fill: float = 0.75,
+                   min_pages: int = 1 << 14) -> int:
+    """Pool pages for ``n_keys`` bulk-loaded at ``fill``: the leaves plus
+    10 % internal overhead plus one chunk of slack, rounded up to a power
+    of two (2^22 at the 100 M-key north star)."""
+    from sherman_tpu.config import LEAF_CAP
+    per_leaf = max(1, int(LEAF_CAP * fill))
+    est = int(n_keys / per_leaf * 1.10) + 8192
+    return max(min_pages, 1 << (est - 1).bit_length())
+
+
+def build_engine(n_nodes: int, pages_per_node: int, batch_per_node: int,
+                 locks_per_node: int = 65_536, chunk_pages: int = 4096,
+                 exchange_impl: str = "xla", gather_impl: str = "xla"):
+    """-> (cluster, tree, engine): a Cluster, its Tree and a BatchedEngine
+    of ``batch_per_node`` rows.  Sibling-chase budget 1: a bulk-loaded
+    tree under read traffic needs height + 1 rounds of descent only."""
+    from sherman_tpu.config import TreeConfig
+    from sherman_tpu.models import batched
+    from sherman_tpu.models.btree import Tree
+
+    cfg = DSMConfig(machine_nr=n_nodes, pages_per_node=pages_per_node,
+                    locks_per_node=locks_per_node,
+                    step_capacity=batch_per_node, chunk_pages=chunk_pages,
+                    exchange_impl=exchange_impl, gather_impl=gather_impl)
+    cluster = Cluster(cfg)
+    tree = Tree(cluster)
+    eng = batched.BatchedEngine(tree, batch_per_node=batch_per_node,
+                                tcfg=TreeConfig(sibling_chase_budget=1))
+    return cluster, tree, eng

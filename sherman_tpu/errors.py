@@ -1,4 +1,4 @@
-"""Typed error taxonomy for the sherman_tpu library.
+"""Typed error hierarchy for the sherman_tpu library.
 
 PR 4 started replacing bare ``ValueError``/``RuntimeError`` raises with
 typed classes (``PallasUnavailableError``, ``ExchangeLaneError``) so

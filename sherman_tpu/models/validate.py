@@ -58,22 +58,46 @@ _STATS = ("keys", "leaves", "internal_pages", "retired", "bad_version",
           "bad_child")
 
 
-def _local_invariants(pool, next_by_node, P: int, N: int) -> dict:
-    """Per-page LOCAL invariant predicates over the whole pool — the
-    shared core of the full validator below and the online scrubber's
-    per-row fault masks (``_scrub_kernel``).  Every mask is [rows]
-    (or [rows, CAP] for the slot/entry matrices); trace-time only.
-    """
+# Rows per lax.map block of the whole-pool kernels: the per-row masks
+# are built block by block so no [rows, CAP] temporary (GBs at the
+# 100 M-key pool on a 16 GB chip) ever exists whole.
+_BLOCK_ROWS = 1 << 16
+
+
+def _blocks(pool, rows: int):
+    """-> (pool viewed [n_blocks, B, PAGE_WORDS], B): blocks of
+    ``_BLOCK_ROWS`` rows (one block below that), the pool zero-padded to
+    a whole number of blocks (no copy when ``rows`` divides evenly, as
+    every power-of-two pool does)."""
+    import jax.numpy as jnp
+    B = min(rows, _BLOCK_ROWS)
+    pad = -rows % B
+    if pad:
+        pool = jnp.pad(pool, ((0, pad), (0, 0)))
+    return pool.reshape(-1, B, C.PAGE_WORDS), B
+
+
+def _rows_of(addr, P: int, N: int):
+    """addr -> (pool row, in range) — BOTH fields bounds-checked: a page
+    >= P would alias into the next node's row range and validate an
+    unrelated page."""
+    import jax.numpy as jnp
+    u = addr.astype(jnp.uint32)
+    node = (u >> C.ADDR_PAGE_BITS).astype(jnp.int32)
+    page = (u & C.ADDR_PAGE_MASK).astype(jnp.int32)
+    ok = (addr != 0) & (node < N) & (page < P)
+    return jnp.clip(node * P + page, 0, N * P - 1), ok
+
+
+def _row_block(pg, ridx, next_by_node, P: int):
+    """The per-page LOCAL predicates of one block of rows ``pg`` [B,
+    PAGE_WORDS] (global row numbers ``ridx``): every output is [B]."""
     import jax.numpy as jnp
 
-    rows = N * P
-    ridx = jnp.arange(rows, dtype=jnp.int32)
-    pg_i = ridx % P
-    nd_i = ridx // P
-    allocated = (pg_i >= 1) & (pg_i < next_by_node[nd_i])
+    allocated = (ridx % P >= 1) & (ridx % P < next_by_node[ridx // P])
 
     def col(w):
-        return pool[:, w]
+        return pg[:, w]
 
     fv = col(C.W_FRONT_VER)
     live = allocated & (fv != 0)
@@ -84,7 +108,6 @@ def _local_invariants(pool, next_by_node, P: int, N: int) -> dict:
     lvl = col(C.W_LEVEL)
     leaf = act & (lvl == 0)
     internal = act & (lvl > 0)
-    bad_ver = act & (fv != col(C.W_REAR_VER))
     # every active page's fences must be strictly ordered.  Beyond local
     # sanity this closes the chain proof: with lowest < highest on every
     # page and sibling.lowest == highest per link, fences strictly
@@ -99,58 +122,67 @@ def _local_invariants(pool, next_by_node, P: int, N: int) -> dict:
     # any occurrence is corruption (the failure class CONFIG_ENABLE_CRC
     # guards in the reference; here the scrubber's bread and butter).
     LC = C.LEAF_CAP
-    sfv, srv = layout.ver_unpack(pool[:, C.L_VER_W:C.L_VER_W + LC])
-    skh = pool[:, C.L_KHI_W:C.L_KHI_W + LC]
-    skl = pool[:, C.L_KLO_W:C.L_KLO_W + LC]
+    sfv, srv = layout.ver_unpack(pg[:, C.L_VER_W:C.L_VER_W + LC])
+    skh = pg[:, C.L_KHI_W:C.L_KHI_W + LC]
+    skl = pg[:, C.L_KLO_W:C.L_KLO_W + LC]
     s_live = (sfv == srv) & (sfv != 0)
     in_f = (bits.key_le(lo_hi[:, None], lo_lo[:, None], skh, skl)
             & bits.key_lt(skh, skl, hi_hi[:, None], hi_lo[:, None]))
     leaf_slots = leaf[:, None] & s_live
-    bad_slot_rows = (leaf_slots & ~in_f).sum(axis=-1)
-    torn_slot_rows = (leaf[:, None] & (sfv != srv)).sum(axis=-1)
 
     # internal entries strictly ascending
     IC = C.INTERNAL_CAP
-    ikh = pool[:, C.I_KHI_W:C.I_KHI_W + IC]
-    ikl = pool[:, C.I_KLO_W:C.I_KLO_W + IC]
+    ikh = pg[:, C.I_KHI_W:C.I_KHI_W + IC]
+    ikl = pg[:, C.I_KLO_W:C.I_KLO_W + IC]
     nk = col(C.W_NKEYS)
     pos = jnp.arange(IC, dtype=jnp.int32)
     asc = bits.key_lt(ikh[:, :-1], ikl[:, :-1], ikh[:, 1:], ikl[:, 1:])
     pair_valid = internal[:, None] & (pos[None, 1:] < nk[:, None])
-    bad_order_rows = (pair_valid & ~asc).sum(axis=-1)
 
-    # addr -> pool row (single-word gathers only)
-    def rows_of(addr):
-        u = addr.astype(jnp.uint32)
-        node = (u >> C.ADDR_PAGE_BITS).astype(jnp.int32)
-        page = (u & C.ADDR_PAGE_MASK).astype(jnp.int32)
-        # BOTH fields bounds-checked: a page >= P would alias into the
-        # next node's row range and validate an unrelated page
-        ok = (addr != 0) & (node < N) & (page < P)
-        return jnp.clip(node * P + page, 0, rows - 1), ok
+    return dict(act=act, retired=retired, leaf=leaf, internal=internal,
+                lvl=lvl, sib=col(C.W_SIBLING), lm=col(C.W_LEFTMOST),
+                nk=nk, lo_hi=lo_hi, lo_lo=lo_lo, hi_hi=hi_hi, hi_lo=hi_lo,
+                bad_ver=act & (fv != col(C.W_REAR_VER)),
+                bad_fence=bad_fence,
+                n_slots=leaf_slots.sum(axis=-1),
+                bad_slot_rows=(leaf_slots & ~in_f).sum(axis=-1),
+                torn_slot_rows=(leaf[:, None] & (sfv != srv)).sum(axis=-1),
+                bad_order_rows=(pair_valid & ~asc).sum(axis=-1))
+
+
+def _local_invariants(pool, next_by_node, P: int, N: int) -> dict:
+    """Per-page LOCAL invariant predicates over the whole pool — the
+    shared core of the full validator below and the online scrubber's
+    per-row fault masks (``_scrub_kernel``).  Every mask is [rows],
+    built block by block (``_row_block``); trace-time only.
+    """
+    import jax.numpy as jnp
+    from jax import lax
+
+    rows = N * P
+    blocks, B = _blocks(pool, rows)
+    base = jnp.arange(blocks.shape[0], dtype=jnp.int32) * B
+    m = lax.map(lambda a: _row_block(
+        a[0], a[1] + jnp.arange(B, dtype=jnp.int32), next_by_node, P),
+        (blocks, base))
+    m = {k: v.reshape(-1)[:rows] for k, v in m.items()}
 
     # B-link continuity per link
-    sib = col(C.W_SIBLING)
-    srow, s_in_range = rows_of(sib)
+    act, lvl, sib = m["act"], m["lvl"], m["sib"]
+    srow, s_in_range = _rows_of(sib, P, N)
     has_sib = act & (sib != 0)
-    bad_sib = has_sib & (
+    m["bad_sib"] = has_sib & (
         ~s_in_range | ~act[srow] | (lvl[srow] != lvl)
-        | (lo_hi[srow] != hi_hi) | (lo_lo[srow] != hi_lo))
-
-    return dict(rows=rows, act=act, retired=retired, leaf=leaf,
-                internal=internal, lvl=lvl, sib=sib, srow=srow,
-                lo_hi=lo_hi, lo_lo=lo_lo, hi_hi=hi_hi, hi_lo=hi_lo,
-                bad_ver=bad_ver, bad_fence=bad_fence,
-                leaf_slots=leaf_slots, bad_slot_rows=bad_slot_rows,
-                torn_slot_rows=torn_slot_rows,
-                bad_order_rows=bad_order_rows, bad_sib=bad_sib,
-                has_sib=has_sib, ikh=ikh, ikl=ikl, nk=nk, pos=pos,
-                rows_of=rows_of)
+        | (m["lo_hi"][srow] != m["hi_hi"])
+        | (m["lo_lo"][srow] != m["hi_lo"]))
+    m.update(rows=rows, srow=srow, has_sib=has_sib)
+    return m
 
 
 @functools.partial(jax.jit, static_argnames=("P", "N"))
 def _validate_kernel(pool, next_by_node, freed, P: int, N: int):
     import jax.numpy as jnp
+    from jax import lax
 
     m = _local_invariants(pool, next_by_node, P, N)
     rows = m["rows"]
@@ -159,16 +191,7 @@ def _validate_kernel(pool, next_by_node, freed, P: int, N: int):
     lo_hi, lo_lo = m["lo_hi"], m["lo_lo"]
     hi_hi, hi_lo = m["hi_hi"], m["hi_lo"]
     bad_ver, bad_fence, bad_sib = m["bad_ver"], m["bad_fence"], m["bad_sib"]
-    ikh, ikl, nk, pos = m["ikh"], m["ikl"], m["nk"], m["pos"]
-    rows_of, srow, has_sib = m["rows_of"], m["srow"], m["has_sib"]
-    sib = m["sib"]
-    bad_slot = m["bad_slot_rows"].sum()
-    torn_slot = m["torn_slot_rows"].sum()
-    bad_order = m["bad_order_rows"].sum()
-    n_keys = m["leaf_slots"].sum()
-
-    def is_act(rowv):  # target-page liveness (act recomputed by gather)
-        return act[rowv]
+    srow, has_sib, sib = m["srow"], m["has_sib"], m["sib"]
 
     # -- 5. leaf-chain shape via in-degrees ----------------------------------
     link_src = leaf & has_sib
@@ -183,8 +206,8 @@ def _validate_kernel(pool, next_by_node, freed, P: int, N: int):
 
     # -- 6. parent/child coherence -------------------------------------------
     IC = C.INTERNAL_CAP
-    lm = pool[:, C.W_LEFTMOST]
-    lmrow, lm_ok = rows_of(lm)
+    lm = m["lm"]
+    lmrow, lm_ok = _rows_of(lm, P, N)
     # a PARKED page — retired (zero high fence) but still this parent's
     # leftmost child — is legal: reclaim cannot drop a leftmost pointer
     # (batched.py _remove_parent_entries), so the page stays retired
@@ -196,13 +219,12 @@ def _validate_kernel(pool, next_by_node, freed, P: int, N: int):
     # mask a dangling parent entry to a freed page — the exact
     # corruption quarantine exists to prevent — would pass until reuse.
     ref_ok = retired & ~freed
-    lm_live_ok = is_act(lmrow) | ref_ok[lmrow]
+    live_or_parked = act | ref_ok
     bad_lm = internal & (
-        (lm == 0) | ~lm_ok | ~lm_live_ok | (lvl[lmrow] != lvl - 1)
+        (lm == 0) | ~lm_ok | ~live_or_parked[lmrow]
+        | (lvl[lmrow] != lvl - 1)
         | (lo_hi[lmrow] != lo_hi) | (lo_lo[lmrow] != lo_lo))
-    iptr = pool[:, C.I_PTR_W:C.I_PTR_W + IC]
-    crow, c_ok = rows_of(iptr)
-    e_valid = internal[:, None] & (pos[None, :] < nk[:, None])
+
     # a RETIRED child with matching level+lowest is in-flight reclaim
     # state (unlinked, parent-entry removal pending retry — the
     # pending_parent set; a restored cluster's reclaim sweeps it), not
@@ -210,22 +232,36 @@ def _validate_kernel(pool, next_by_node, freed, P: int, N: int):
     # rewrites the fences, so the lowest-key clause flags the entry —
     # and a freed-NOT-YET-reused page is caught by the freed mask
     # (ref_ok above), closing the window between free and reuse.
-    bad_child = e_valid & (
-        ~c_ok | ~(is_act(crow) | ref_ok[crow])
-        | (lvl[crow] != (lvl - 1)[:, None])
-        | (lo_hi[crow] != ikh) | (lo_lo[crow] != ikl))
+    blocks, B = _blocks(pool, rows)
+    pos = jnp.arange(IC, dtype=jnp.int32)
+
+    def child_block(a):
+        pg, internal_b, lvl_b, nk_b = a
+        crow, c_ok = _rows_of(pg[:, C.I_PTR_W:C.I_PTR_W + IC], P, N)
+        e_valid = internal_b[:, None] & (pos[None, :] < nk_b[:, None])
+        return (e_valid & (
+            ~c_ok | ~live_or_parked[crow]
+            | (lvl[crow] != (lvl_b - 1)[:, None])
+            | (lo_hi[crow] != pg[:, C.I_KHI_W:C.I_KHI_W + IC])
+            | (lo_lo[crow] != pg[:, C.I_KLO_W:C.I_KLO_W + IC]))).sum()
+
+    per_block = lambda x: jnp.pad(
+        x, (0, blocks.shape[0] * B - rows)).reshape(-1, B)
+    bad_child = lax.map(child_block, (blocks, per_block(internal),
+                                      per_block(lvl),
+                                      per_block(m["nk"]))).sum()
 
     # int32 counts are ample (< 2^31 pages/keys per cluster by
     # construction; jax x64 is disabled anyway)
     return jnp.stack([
-        n_keys.astype(jnp.int32),
+        m["n_slots"].sum().astype(jnp.int32),
         leaf.sum(), internal.sum(), retired.sum(), bad_ver.sum(),
-        bad_fence.sum(), bad_slot.astype(jnp.int32),
-        torn_slot.astype(jnp.int32),
-        bad_order.astype(jnp.int32),
+        bad_fence.sum(), m["bad_slot_rows"].sum().astype(jnp.int32),
+        m["torn_slot_rows"].sum().astype(jnp.int32),
+        m["bad_order_rows"].sum().astype(jnp.int32),
         bad_sib.sum(), heads.sum(), bad_head.sum(),
         tails.sum(), bad_tail.sum(), multi_in.sum(), bad_lm.sum(),
-        bad_child.sum()])
+        bad_child.astype(jnp.int32)])
 
 
 # ---------------------------------------------------------------------------

@@ -33,14 +33,12 @@ single-process.  This module is the service half:
   (``JournalTailer(dir, cid, host_id=A)``) — same shared
   ``apply_records`` core, now cross-host.
 
-**Scope honesty.**  This container's jaxlib (0.4.37 CPU) has no
-multiprocess collectives, so the plane is exercised via EMULATION: N
+**Scope honesty.**  The plane is exercised via EMULATION: N
 host contexts (N single-process clusters = N chain namespaces + one
 routing table) in one process.  Every file-format, routing, recovery
 and replication path is the real code; the transport (one mesh
 spanning processes) is not — true 2-process drills stay gated behind
-:func:`multihost_capable` (the conftest probe, re-homed here so bench
-receipts can stamp it) and real-pod captures are queued in
+:func:`multihost_capable` (the conftest probe) and real-pod captures are queued in
 BENCHMARKS.md.  ``SHERMAN_HOSTS=1`` (the shipped default) constructs
 no plane at all: artifact names, journal bytes and receipts are
 bit-identical to a build without this module.
@@ -94,9 +92,7 @@ def multihost_capable() -> tuple[bool, str]:
     cross-process allgather with a deadline), subprocess-isolated so
     the probe can neither poison nor be poisoned by this process's jax
     runtime.  Gates the true 2-process drills
-    (``tests/test_multihost.py``) and is stamped into bench receipts
-    (``config.multihost_capable``) so chip-session artifacts are
-    self-describing about which transport they exercised."""
+    (``tests/test_multihost.py``)."""
     if _MULTIHOST_PROBE:
         return _MULTIHOST_PROBE[0]
     import os

@@ -15,14 +15,18 @@ outside the XLA data plane:
 - ``LocalLockTable``   — ticket locks with bounded hand-over
                          (Tree.cpp:1124-1173 role)
 
-Built on first import with ``g++`` into ``build/libsherman_native.so``
-(rebuilt when any source is newer).  ``available()`` reports whether the
+Built on first use with ``g++`` into
+``build/libsherman_native-<hash>.so``, where the hash covers the contents
+of ``src/*`` and the compile command — only the committed sources can
+produce the library that loads.  ``available()`` reports whether the
 library loaded; callers keep pure-Python fallbacks where one exists.
 """
 
 from __future__ import annotations
 
 import ctypes as ct
+import glob
+import hashlib
 import os
 import subprocess
 import tempfile
@@ -35,7 +39,8 @@ from sherman_tpu.errors import (ConfigError, NativeBuildError,
 _DIR = os.path.dirname(os.path.abspath(__file__))
 _SRC = os.path.join(_DIR, "src")
 _BUILD = os.path.join(_DIR, "build")
-_LIB = os.path.join(_BUILD, "libsherman_native.so")
+_CMD = ("g++", "-O3", "-std=c++17", "-shared", "-fPIC", "-pthread",
+        "-fvisibility=hidden")
 
 _lib = None
 _load_error: str | None = None
@@ -46,29 +51,38 @@ def _sources() -> list[str]:
         os.path.join(_SRC, f) for f in os.listdir(_SRC) if f.endswith(".cc"))
 
 
-def _stale() -> bool:
-    if not os.path.exists(_LIB):
-        return True
-    t = os.path.getmtime(_LIB)
-    deps = _sources() + [
-        os.path.join(_SRC, f) for f in os.listdir(_SRC) if f.endswith(".h")]
-    return any(os.path.getmtime(s) > t for s in deps)
+def _lib_path() -> str:
+    """The library's path, keyed by the sources' contents and the
+    compile command (never by mtimes)."""
+    h = hashlib.sha256(" ".join(_CMD).encode())
+    for p in sorted(os.path.join(_SRC, f) for f in os.listdir(_SRC)
+                    if f.endswith((".cc", ".h"))):
+        h.update(os.path.basename(p).encode())
+        with open(p, "rb") as fh:
+            h.update(fh.read())
+    return os.path.join(_BUILD,
+                        f"libsherman_native-{h.hexdigest()[:16]}.so")
 
 
-def _build() -> None:
+def _build(lib: str) -> None:
     os.makedirs(_BUILD, exist_ok=True)
     fd, tmp = tempfile.mkstemp(suffix=".so", dir=_BUILD)
     os.close(fd)
-    cmd = ["g++", "-O3", "-std=c++17", "-shared", "-fPIC", "-pthread",
-           "-fvisibility=hidden", "-o", tmp] + _sources()
+    cmd = list(_CMD) + ["-o", tmp] + _sources()
     try:
         subprocess.run(cmd, check=True, capture_output=True, text=True)
-        os.replace(tmp, _LIB)  # atomic under concurrent builders
+        os.replace(tmp, lib)  # atomic under concurrent builders
     except subprocess.CalledProcessError as e:
         raise NativeBuildError(f"native build failed:\n{e.stderr}") from e
     finally:
         if os.path.exists(tmp):
             os.unlink(tmp)
+    for old in glob.glob(os.path.join(_BUILD, "libsherman_native*.so")):
+        if old != lib:  # libraries of other sources never load again
+            try:
+                os.unlink(old)
+            except OSError:
+                pass
 
 
 def _sig(name: str, res, args) -> None:
@@ -83,9 +97,10 @@ def _load() -> None:
     if _lib is not None or _load_error is not None:
         return
     try:
-        if _stale():
-            _build()
-        _lib = ct.CDLL(_LIB)
+        lib = _lib_path()
+        if not os.path.exists(lib):
+            _build(lib)
+        _lib = ct.CDLL(lib)
     except (OSError, RuntimeError) as e:  # no g++ / bad toolchain
         _load_error = str(e)
         return

@@ -12,7 +12,7 @@ device-side hazards no black-box gauge can see:
   recorded as a structured entry ``{program label, abstract-shape
   signature, compile ms, count}``.  Compiles are observed two ways at
   once: a ``jax.monitoring`` duration listener (the
-  ``backend_compile`` events, present on this 0.4.37 toolchain)
+  ``backend_compile`` events)
   attributes compile *walls* to the program whose dispatch triggered
   them, and a per-program wrapper (:meth:`CompileLedger.wrap`, applied
   at the engine/staged jit-cache sites) detects the compile itself via
@@ -38,10 +38,10 @@ device-side hazards no black-box gauge can see:
   a measured phase wall by :func:`roofline` into
   ``achieved_bytes_frac`` / ``achieved_flops_frac`` against the
   device's peak HBM bandwidth and peak flops
-  (:func:`device_peaks`: known TPU generations by ``device_kind``,
-  overridable via ``SHERMAN_PEAK_GBPS`` / ``SHERMAN_PEAK_TFLOPS``;
-  unknown backends publish absolute achieved rates and leave the
-  fractions out rather than invent a peak).
+  (:func:`device_peaks`: known TPU generations by ``device_kind``; a
+  TPU kind missing from the table is an error.  Off the chip,
+  ``SHERMAN_PEAK_GBPS`` / ``SHERMAN_PEAK_TFLOPS`` may stand in for test
+  rigs, and otherwise the fractions are left out rather than invented).
 
 Process-wide default: :func:`get_ledger` / :func:`get_accountant`
 register the ``device.`` pull collector on first access, so every
@@ -63,6 +63,7 @@ import os
 import threading
 import time
 
+from sherman_tpu.errors import ShermanError
 from sherman_tpu.obs import recorder as _recorder
 from sherman_tpu.obs import registry as _registry
 
@@ -476,7 +477,9 @@ def _unwrap(fn):
 
 def program_cost(fn, *args, _ledger=None, **kwargs) -> dict:
     """flops/bytes of one program via ``lowered.cost_analysis()`` (no
-    second backend compile).  Graceful: any failure returns the typed
+    second backend compile) — or, on a TPU, where the lowered form
+    answers None, the compiled executable's (the persistent compile
+    cache absorbs that compile).  Graceful: any failure returns the typed
     ``{"available": False, "reason": ...}`` instead of raising — the
     receipts column reads "unavailable", the run does not die."""
     led = _ledger or get_ledger()
@@ -484,6 +487,8 @@ def program_cost(fn, *args, _ledger=None, **kwargs) -> dict:
         with led.suppress():
             low = _unwrap(fn).lower(*args, **kwargs)
             ca = low.cost_analysis()
+            if ca is None:
+                ca = low.compile().cost_analysis()
         if isinstance(ca, (list, tuple)):   # per-partition form
             ca = ca[0] if ca else {}
         flops = float(ca.get("flops", 0.0) or 0.0)
@@ -521,8 +526,8 @@ def program_memory(fn, *args, _ledger=None, **kwargs) -> dict:
 
 # peak (HBM bytes/s, flops/s) by TPU device_kind substring — the roofline
 # ceilings fractions are computed against.  Sources: published TPU specs
-# (bf16 peak flops; HBM BW).  Env overrides win (SHERMAN_PEAK_GBPS /
-# SHERMAN_PEAK_TFLOPS) so a new device kind needs no code change.
+# (bf16 peak flops; HBM BW) — v5e: Google Cloud documentation "TPU v5e"
+# (197 TFLOP/s bf16, 819 GB/s HBM).
 _KNOWN_PEAKS = (
     ("v5p", 2765e9, 459e12),
     ("v5 lite", 819e9, 197e12),  # libtpu reports v5e as "TPU v5 lite"
@@ -535,16 +540,40 @@ _KNOWN_PEAKS = (
 )
 
 
-def device_peaks() -> dict:
-    """{"bytes_per_s", "flops_per_s", "source"} for device 0 — each
-    peak resolves independently: a valid env override wins, otherwise
-    the known-TPU table (so overriding just the bandwidth on a known
-    part keeps the table's flops roof); a malformed override is flagged
-    in ``source`` and falls back like an unset one — this only runs at
-    end-of-run section build, after all the timed windows, and a typo
-    must not cost the run its receipt.  Unknown backends (this CPU
-    mesh) leave unresolved peaks None so fractions are omitted, never
-    invented."""
+class UnknownDeviceKindError(ShermanError, LookupError):
+    """A TPU whose ``device_kind`` has no row in the peak table: a
+    roofline fraction against a guessed peak would be a made-up number,
+    so the table must learn the part first."""
+
+    def __init__(self, kind: str):
+        super().__init__(
+            f"no peak HBM bandwidth / flops known for TPU device_kind "
+            f"{kind!r}: add it to obs/device.py _KNOWN_PEAKS with its "
+            "published source")
+        self.kind = kind
+
+
+def device_peaks(platform: str | None = None,
+                 kind: str | None = None) -> dict:
+    """{"bytes_per_s", "flops_per_s", "source"} for device 0 (or the
+    given ``platform``/``kind``).  On a TPU the peaks come from the
+    table only and an unknown kind raises :class:`UnknownDeviceKindError`.
+    Off the chip (the CPU test mesh) there is no table: the
+    ``SHERMAN_PEAK_GBPS`` / ``SHERMAN_PEAK_TFLOPS`` overrides stand in
+    for test rigs, a malformed one is flagged in ``source``, and unset
+    peaks stay None so fractions are omitted, never invented."""
+    if platform is None or kind is None:
+        import jax
+        dev = jax.devices()[0]
+        platform = dev.platform if platform is None else platform
+        kind = dev.device_kind if kind is None else kind
+    kind = kind.lower()
+    if platform == "tpu":
+        for token, tbw, tfl in _KNOWN_PEAKS:
+            if token in kind:
+                return {"bytes_per_s": tbw, "flops_per_s": tfl,
+                        "source": f"device_kind:{kind}"}
+        raise UnknownDeviceKindError(kind)
     notes = []
 
     def _env(var: str, scale: float):
@@ -559,20 +588,9 @@ def device_peaks() -> dict:
 
     bw = _env("SHERMAN_PEAK_GBPS", 1e9)
     fl = _env("SHERMAN_PEAK_TFLOPS", 1e12)
-    try:
-        import jax
-        kind = jax.devices()[0].device_kind.lower()
-    except Exception:
-        kind = ""
-    table = next(((tbw, tfl) for token, tbw, tfl in _KNOWN_PEAKS
-                  if token in kind), None)
     if bw is not None or fl is not None:
         notes.append("env")
-    if table is not None and (bw is None or fl is None):
-        notes.append(f"device_kind:{kind}")
-        bw = table[0] if bw is None else bw
-        fl = table[1] if fl is None else fl
-    elif table is None and (bw is None or fl is None):
+    if bw is None or fl is None:
         notes.append(f"unknown:{kind or 'no-device'}")
     return {"bytes_per_s": bw, "flops_per_s": fl,
             "source": ";".join(notes)}

@@ -83,10 +83,7 @@ def gather_rows(x, axis_name: str):
     Traced-issue accounting follows :func:`exchange`'s convention: one
     inc per collective per program BUILD, bytes = the per-step GLOBAL
     payload every node receives."""
-    if hasattr(jax.lax, "axis_size"):
-        n = jax.lax.axis_size(axis_name)
-    else:  # JAX < 0.5: psum of a literal folds to a static int
-        n = jax.lax.psum(1, axis_name)
+    n = jax.lax.axis_size(axis_name)
     _OBS_AG_ISSUES.inc()
     _OBS_AG_BYTES.inc(int(x.size) * x.dtype.itemsize * int(n))
     return jax.lax.all_gather(x, axis_name, axis=0, tiled=True)
@@ -103,15 +100,10 @@ def exchange(tree, axis_name: str, *, impl: str = "xla"):
     """
     if impl == "pallas":
         from sherman_tpu.parallel import transport_pallas
-        if hasattr(jax.lax, "axis_size"):
-            n_nodes = jax.lax.axis_size(axis_name)
-        else:  # JAX < 0.5: psum of a literal folds to a static int
-            n_nodes = jax.lax.psum(1, axis_name)
-        interpret = jax.default_backend() != "tpu"
         _OBS_XCH_PALLAS.inc()
         _OBS_XCH_BYTES.inc(_tree_nbytes(tree))
-        return transport_pallas.exchange(tree, axis_name, n_nodes,
-                                         interpret=interpret)
+        return transport_pallas.exchange(
+            tree, axis_name, jax.lax.axis_size(axis_name))
     _OBS_XCH_ISSUES.inc(len(jax.tree.leaves(tree)))
     _OBS_XCH_BYTES.inc(_tree_nbytes(tree))
     return jax.tree.map(

@@ -17,17 +17,12 @@ semaphores as the completion queue.  Per step and per peer:
 
 Parity/selection: ``DSMConfig.exchange_impl = "xla" | "pallas"`` switches
 the DSM step's exchanges.  The Pallas path is validated in interpreter mode
-on the virtual CPU mesh (tests); the XLA path remains the default
-(compiler-scheduled, equal-or-faster, and exempt from Mosaic toolchain
-constraints).  COVERAGE: the pre-post cluster barrier (``use_barrier``)
-cannot run in the interpreter (it cannot lower ``get_barrier_semaphore``
-and runs devices sequentially), but the full compiled form — barrier
-included — is COMPILE-SMOKED without multi-chip hardware: the 8-device
-program is lowered for the TPU target through the Pallas->Mosaic pipeline
-over an ``AbstractMesh`` (``tests/test_transport_pallas.py::
-test_multichip_tpu_lowering_smoke``), which verifies the semaphore
-signal/wait and remote-copy lowering.  EXECUTING the barrier still needs
-real multi-chip hardware; until then treat "pallas" as experimental there.
+on the virtual CPU mesh (tests); the XLA path remains the default.  The
+pre-post cluster barrier (``use_barrier``) cannot run in the interpreter
+(it cannot lower ``get_barrier_semaphore`` and runs devices sequentially);
+the compiled form, barrier included, is compiled for a described 4-chip
+v5e in ``tests/test_tpu_compile.py``,
+and run on four chips by ``chip_smoke.py --chips 4``.
 
 Layout contract (same as ``transport.exchange`` with tiled all_to_all):
 arrays are ``[N * C, ...]`` per node — row block ``d*C:(d+1)*C`` is the
@@ -46,14 +41,11 @@ try:  # pallas is TPU-oriented; CPU uses interpreter mode
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
     HAVE_PALLAS = True
-    # JAX < 0.5 spells CompilerParams TPUCompilerParams
-    _CompilerParams = getattr(pltpu, "CompilerParams",
-                              getattr(pltpu, "TPUCompilerParams", None))
 except Exception:  # pragma: no cover
     HAVE_PALLAS = False
 
 from sherman_tpu import obs
-from sherman_tpu.ops.pallas_page import PallasUnavailableError
+from sherman_tpu.ops.pallas_page import PallasUnavailableError, interpret_mode
 from sherman_tpu.errors import ShermanError
 
 
@@ -92,11 +84,12 @@ def _collective_id(n_nodes: int, rows: int, width: int) -> int:
 
 
 def _exchange_kernel(x_ref, out_ref, send_sem, recv_sem, *, n_nodes: int,
-                     rows_per_peer: int, axis_name: str,
-                     use_barrier: bool):
-    """All-to-all of per-peer row blocks via N-1 one-sided remote writes."""
+                     axis_name: str, use_barrier: bool):
+    """All-to-all of per-peer blocks via N-1 one-sided remote writes.
+    ``x_ref``/``out_ref`` are [N, K, 128]: block ``d`` is the payload
+    for/from peer ``d``, whole (8, 128) tiles, so every DMA is
+    tile-aligned."""
     my = jax.lax.axis_index(axis_name)
-    C = rows_per_peer
 
     # Cluster barrier BEFORE posting any one-sided write: without it a
     # fast device can race ahead into the NEXT exchange kernel and its
@@ -114,11 +107,8 @@ def _exchange_kernel(x_ref, out_ref, send_sem, recv_sem, *, n_nodes: int,
         pltpu.semaphore_wait(bar, n_nodes - 1)
 
     # local bucket: plain local DMA (no network)
-    local = pltpu.make_async_copy(
-        x_ref.at[pl.ds(my * C, C)],
-        out_ref.at[pl.ds(my * C, C)],
-        send_sem.at[0],
-    )
+    local = pltpu.make_async_copy(x_ref.at[my], out_ref.at[my],
+                                  send_sem.at[0])
     local.start()
 
     # post every remote write first (doorbell batch), then wait all.
@@ -129,8 +119,8 @@ def _exchange_kernel(x_ref, out_ref, send_sem, recv_sem, *, n_nodes: int,
     for k in range(1, n_nodes):
         peer = jax.lax.rem(my + k, n_nodes)
         rdma = pltpu.make_async_remote_copy(
-            src_ref=x_ref.at[pl.ds(peer * C, C)],
-            dst_ref=out_ref.at[pl.ds(my * C, C)],
+            src_ref=x_ref.at[peer],
+            dst_ref=out_ref.at[my],
             send_sem=send_sem.at[k],
             recv_sem=recv_sem.at[k],
             device_id=peer,
@@ -145,37 +135,48 @@ def _exchange_kernel(x_ref, out_ref, send_sem, recv_sem, *, n_nodes: int,
 
 
 def exchange_pallas(x, axis_name: str, n_nodes: int, *,
-                    interpret: bool = False):
+                    interpret: bool | None = None):
     """Pallas remote-DMA all_to_all of one [N*C, W] int32 array.
 
     Call inside shard_map on per-node shards.  Equivalent to
-    ``lax.all_to_all(x, axis_name, 0, 0, tiled=True)``.
+    ``lax.all_to_all(x, axis_name, 0, 0, tiled=True)``.  Each peer's
+    C*W words travel as a lane-dense [K, 128] block (zero-padded to
+    whole tiles), whatever W is.
     """
     if not HAVE_PALLAS:
         raise PallasUnavailableError("DSMConfig.exchange_impl")
+    interpret = interpret_mode(interpret)
     rows = x.shape[0]
     assert rows % n_nodes == 0
     C = rows // n_nodes
+    words = C * math.prod(x.shape[1:])
+    tile = 8 * 128
+    padded = -(-words // tile) * tile
     _OBS_REMOTE_WRITES.inc(n_nodes - 1)
     _OBS_PACKED_BYTES.inc(x.size * x.dtype.itemsize)
+    blocks = jnp.pad(x.reshape(n_nodes, words),
+                     ((0, 0), (0, padded - words)))
+    blocks = blocks.reshape(n_nodes, padded // 128, 128)
     kernel = functools.partial(
-        _exchange_kernel, n_nodes=n_nodes, rows_per_peer=C,
-        axis_name=axis_name, use_barrier=not interpret)
-    return pl.pallas_call(
+        _exchange_kernel, n_nodes=n_nodes, axis_name=axis_name,
+        use_barrier=not interpret)
+    out = pl.pallas_call(
         kernel,
-        out_shape=jax.ShapeDtypeStruct(x.shape, x.dtype),
+        out_shape=jax.ShapeDtypeStruct(blocks.shape, x.dtype),
         in_specs=[pl.BlockSpec(memory_space=pl.ANY)],
         out_specs=pl.BlockSpec(memory_space=pl.ANY),
         scratch_shapes=[pltpu.SemaphoreType.DMA((n_nodes,)),
                         pltpu.SemaphoreType.DMA((n_nodes,))],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             collective_id=_collective_id(
                 n_nodes, C, math.prod(x.shape[1:]))),
         interpret=interpret,
-    )(x)
+    )(blocks)
+    return out.reshape(n_nodes, padded)[:, :words].reshape(x.shape)
 
 
-def exchange(tree, axis_name: str, n_nodes: int, *, interpret: bool = False):
+def exchange(tree, axis_name: str, n_nodes: int, *,
+             interpret: bool | None = None):
     """Drop-in for ``transport.exchange``: the whole pytree is packed into
     ONE [N*C, sum(W)] int32 buffer and rides one kernel — one barrier and
     N-1 posted writes per step, however many request fields there are.

@@ -422,3 +422,35 @@ def test_repl_chaos_hold_heal_and_lease_freeze():
     assert layer.lease_view(live) == {7: 1}  # still the old world
     layer.heal()
     assert layer.lease_view(live) == {7: 2}  # live again
+
+
+@pytest.mark.parametrize("block_rows", [1000, 3000])
+def test_validator_blocks_pad_a_ragged_pool(small_cluster, monkeypatch,
+                                            block_rows):
+    """A pool whose row count is no multiple of the block is zero-padded
+    to whole blocks, never cut into tiny ones: same stats, and a torn
+    page is found at the same address."""
+    from sherman_tpu.models import validate as V
+    cluster, tree, eng, keys = small_cluster
+
+    victim, _ = _victim(tree, keys)
+
+    def both():
+        stats = check_structure_device(tree)
+        _fire(cluster.dsm, CH.FaultPlan(
+            [CH.Fault(kind="flip_entry_ver", step=0, addr=victim, slot=2)]))
+        return stats, scrub_pass(tree)["bad"]
+
+    want_stats = check_structure_device(tree)
+    monkeypatch.setattr(V, "_BLOCK_ROWS", block_rows)
+    for k in (V._validate_kernel, V._scrub_kernel):
+        k.clear_cache()
+    try:
+        got_stats, got_bad = both()
+    finally:
+        monkeypatch.undo()
+        for k in (V._validate_kernel, V._scrub_kernel):
+            k.clear_cache()
+    assert got_stats == want_stats
+    assert got_bad == scrub_pass(tree)["bad"] and got_bad
+    assert got_bad[0][0] == victim

@@ -158,10 +158,29 @@ def test_default_ledger_registers_device_collector():
 
 # -- cost / memory analysis ----------------------------------------------------
 
-def test_program_cost_and_memory_on_cpu():
+class _TpuLikeLowered:
+    """A lowering that, like a TPU's, answers cost analysis with None
+    and leaves it to the compiled executable."""
+
+    def __init__(self, low):
+        self._low = low
+
+    def cost_analysis(self):
+        return None
+
+    def compile(self):
+        return self._low.compile()
+
+
+@pytest.mark.parametrize("tpu_like", [False, True])
+def test_program_cost_and_memory_on_cpu(tpu_like):
+    import types
+
     import jax
 
-    f = jax.jit(lambda x: (x.astype(np.float32) * 2.0).sum())
+    jf = jax.jit(lambda x: (x.astype(np.float32) * 2.0).sum())
+    f = types.SimpleNamespace(
+        lower=lambda *a: _TpuLikeLowered(jf.lower(*a))) if tpu_like else jf
     x = np.arange(1024, dtype=np.int32)
     c = dev.program_cost(f, x)
     assert c["available"] and c["flops"] > 0 and c["bytes"] > 0
@@ -231,6 +250,25 @@ def test_device_peaks_env_fields_resolve_independently(monkeypatch):
     assert peaks["flops_per_s"] == pytest.approx(197e12)
     assert "bad-env:SHERMAN_PEAK_GBPS" in peaks["source"]
     assert "env" in peaks["source"].split(";")
+
+
+@pytest.mark.parametrize("kind,gbps", [("TPU v5 lite", 819), ("TPU v6 lite", 1640),
+                                       ("TPU v4", 1228)])
+def test_device_peaks_tpu_table_ignores_env(monkeypatch, kind, gbps):
+    # on a TPU the table is the only source: an env override never
+    # stands in for a known part's published peak
+    monkeypatch.setenv("SHERMAN_PEAK_GBPS", "1")
+    peaks = dev.device_peaks("tpu", kind)
+    assert peaks["bytes_per_s"] == pytest.approx(gbps * 1e9)
+    assert peaks["source"] == f"device_kind:{kind.lower()}"
+
+
+def test_device_peaks_unknown_tpu_kind_raises(monkeypatch):
+    monkeypatch.setenv("SHERMAN_PEAK_GBPS", "100")
+    monkeypatch.setenv("SHERMAN_PEAK_TFLOPS", "100")
+    with pytest.raises(dev.UnknownDeviceKindError) as ei:
+        dev.device_peaks("tpu", "TPU v99 prototype")
+    assert "_KNOWN_PEAKS" in str(ei.value)
 
 
 def test_roofline_unknown_backend_omits_fractions():

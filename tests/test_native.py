@@ -7,6 +7,7 @@ ticket-lock hand-over protocol (Tree.cpp:1124-1173), the zipf sampler, and
 the latency histogram (benchmark.cpp:207-249 cal_latency role).
 """
 
+import os
 import threading
 
 import numpy as np
@@ -407,3 +408,23 @@ def test_prep_zipf_distribution_matches_exact_sampler():
     fast_head = np.isin(buf.keys, r2k).mean()
     exact_head = (exact < lut_n).mean()
     assert abs(fast_head - exact_head) < 0.02, (fast_head, exact_head)
+
+
+def test_library_keyed_by_source_content(tmp_path, monkeypatch):
+    """The library that loads is named by a hash of src/* and the
+    compile command: touching a file changes nothing, editing one
+    names a new library (a stale .so can never load for new sources)."""
+    import shutil
+    src = tmp_path / "src"
+    shutil.copytree(native._SRC, src)
+    monkeypatch.setattr(native, "_SRC", str(src))
+    p1 = native._lib_path()
+    cc = sorted(src.glob("*.cc"))[0]
+    os.utime(cc, (1, 1))
+    assert native._lib_path() == p1
+    with open(cc, "a") as f:
+        f.write("\n// edited\n")
+    p2 = native._lib_path()
+    assert p2 != p1
+    monkeypatch.setattr(native, "_CMD", native._CMD + ("-g",))
+    assert native._lib_path() != p2

@@ -1,7 +1,7 @@
 """Pallas page-engine kernels (ops/pallas_page) vs the XLA primitives:
-bit-identical on ANY inputs, interpreter mode on the CPU mesh, TPU-target
-compile smokes without hardware — the transport_pallas coverage recipe
-applied to the HBM<->VMEM data plane.
+bit-identical on ANY inputs, interpreter mode on the CPU mesh — the
+transport_pallas coverage recipe applied to the HBM<->VMEM data plane.
+Their compiles for a v5e live in tests/test_tpu_compile.py.
 
 The fuzz deliberately feeds GARBAGE pools (uniform random words): the
 parity contract is bitwise equality of the kernel and its ``*_xla`` twin
@@ -292,46 +292,13 @@ def test_engine_pool_bit_identity_multinode(eight_devices):
     np.testing.assert_array_equal(pools["xla"], pools["pallas"])
 
 
-# ---------------------------------------------------------------------------
-# TPU-target compile smokes (no hardware needed): the kernels must
-# survive the Pallas->Mosaic lowering for a real chip, the same coverage
-# recipe as test_transport_pallas.test_multichip_tpu_lowering_smoke.
-# ---------------------------------------------------------------------------
-
-def _lower_tpu(fn, *args):
-    try:
-        return jax.jit(fn).trace(*args).lower(
-            lowering_platforms=("tpu",)).as_text()
-    except ValueError as e:  # only known capability gaps may skip
-        if "lowering_platforms" in str(e) or "cross-backend" in str(e):
-            pytest.skip(f"cross-platform TPU lowering unsupported: {e}")
-        raise
-
-
-def test_descent_round_tpu_lowering_smoke():
-    pool = jax.ShapeDtypeStruct((4096, C.PAGE_WORDS), jnp.int32)
-    v = jax.ShapeDtypeStruct((512,), jnp.int32)
-    b = jax.ShapeDtypeStruct((512,), jnp.bool_)
-    txt = _lower_tpu(
-        lambda *a: PP.descent_round(*a, interpret=False), pool, v, v, v, b)
-    assert "tpu_custom_call" in txt or "mosaic" in txt.lower()
-
-
-def test_writeback_tpu_lowering_smoke():
-    pool = jax.ShapeDtypeStruct((4096, C.PAGE_WORDS), jnp.int32)
-    v = jax.ShapeDtypeStruct((512,), jnp.int32)
-    b = jax.ShapeDtypeStruct((512,), jnp.bool_)
-    ent = jax.ShapeDtypeStruct((512, 3), jnp.int32)
-    lanes = (C.L_VER_W, C.L_VHI_W, C.L_VLO_W)
-    txt = _lower_tpu(
-        lambda *a: PP.writeback(*a, field_w=lanes, interpret=False),
-        pool, v, v, b, ent)
-    assert "tpu_custom_call" in txt or "mosaic" in txt.lower()
-
-
-def test_gather_pages_tpu_lowering_smoke():
-    pool = jax.ShapeDtypeStruct((4096, C.PAGE_WORDS), jnp.int32)
-    v = jax.ShapeDtypeStruct((512,), jnp.int32)
-    txt = _lower_tpu(lambda *a: PP.gather_pages(*a, interpret=False),
-                     pool, v)
-    assert "tpu_custom_call" in txt or "mosaic" in txt.lower()
+def test_interpret_mode_never_on_a_tpu(monkeypatch):
+    """Interpreter off the chip only: on a TPU backend the default is
+    compiled, and an explicit interpret=True is refused, not obeyed."""
+    monkeypatch.setattr(PP.jax, "default_backend", lambda: "tpu")
+    assert PP.interpret_mode() is False
+    assert PP.interpret_mode(False) is False
+    with pytest.raises(ValueError):
+        PP.interpret_mode(True)
+    monkeypatch.setattr(PP.jax, "default_backend", lambda: "cpu")
+    assert PP.interpret_mode() is True

@@ -430,6 +430,13 @@ def _repo_root() -> str:
     return os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 
+# synthetic, schema-identical closed-loop receipts (r02-r05): the gate's
+# own pins, independent of any measured record
+_DATA = os.path.join(os.path.dirname(os.path.abspath(__file__)), "data",
+                     "perfgate")
+_R05 = os.path.join(_DATA, "BENCH_r05.json")
+
+
 def _perfgate():
     import importlib
     import sys
@@ -437,22 +444,21 @@ def _perfgate():
     return importlib.import_module("perfgate")
 
 
-def test_perfgate_passes_committed_r05():
+def test_perfgate_passes_its_own_r05():
     pg = _perfgate()
-    rc = pg.main(["--receipt",
-                  os.path.join(_repo_root(), "BENCH_r05.json")])
+    rc = pg.main(["--receipt", _R05, "--repo", _DATA])
     assert rc == 0
 
 
 def test_perfgate_flags_synthetic_regression(tmp_path, capsys):
     pg = _perfgate()
-    cand = pg.load_receipt(os.path.join(_repo_root(), "BENCH_r05.json"))
+    cand = pg.load_receipt(_R05)
     cand.pop("_round", None)  # a fresh receipt gates on the full history
     for k in ("value", "sustained_ops_s", "sus_mixed_ops_s"):
         cand[k] = round(cand[k] * 0.8)  # the -20% acceptance case
     p = str(tmp_path / "degraded.json")
     json.dump(cand, open(p, "w"))
-    assert pg.main(["--receipt", p]) == 1
+    assert pg.main(["--receipt", p, "--repo", _DATA]) == 1
     res = json.loads(capsys.readouterr().out.strip().splitlines()[-1])
     assert not res["ok"]
     assert not res["metrics"]["sustained_ops_s"]["ok"]
@@ -460,23 +466,23 @@ def test_perfgate_flags_synthetic_regression(tmp_path, capsys):
 
 
 def test_perfgate_noise_sized_wiggle_passes(tmp_path):
-    # the calibrated r05 run spread (33.8 vs 32.2 M = ~5%) must NOT trip
+    # a calibrated-spread wiggle (33.8 vs 32.2 = ~5%) must NOT trip
     # the gate: same-build noise is not a regression
     pg = _perfgate()
-    cand = pg.load_receipt(os.path.join(_repo_root(), "BENCH_r05.json"))
+    cand = pg.load_receipt(_R05)
     cand.pop("_round", None)
     for k in ("value", "sustained_ops_s", "sus_mixed_ops_s"):
         cand[k] = round(cand[k] * (32.2 / 33.8))
     p = str(tmp_path / "wiggle.json")
     json.dump(cand, open(p, "w"))
-    assert pg.main(["--receipt", p]) == 0
+    assert pg.main(["--receipt", p, "--repo", _DATA]) == 0
 
 
 def test_perfgate_incomparable_receipt_exits_2(tmp_path):
     pg = _perfgate()
     p = str(tmp_path / "other.json")
     json.dump({"value": 1, "keys": 42, "batch": 7, "p99_ms": 1.0}, open(p, "w"))
-    assert pg.main(["--receipt", p]) == 2
+    assert pg.main(["--receipt", p, "--repo", _DATA]) == 2
 
 
 def test_perfgate_value_config_change_is_incomparable(tmp_path,
@@ -488,7 +494,7 @@ def test_perfgate_value_config_change_is_incomparable(tmp_path,
     against the inline trajectory (missing fields = the pre-heap
     8-byte fixed inline fact)."""
     pg = _perfgate()
-    cand = pg.load_receipt(os.path.join(_repo_root(), "BENCH_r05.json"))
+    cand = pg.load_receipt(_R05)
     cand.pop("_round", None)
     cand.setdefault("config", {})
     cand["config"].update({"value_bytes": 252, "value_dist": "fixed",
@@ -497,21 +503,20 @@ def test_perfgate_value_config_change_is_incomparable(tmp_path,
         cand[k] = round(cand[k] * 0.5)
     p = str(tmp_path / "heapcfg.json")
     json.dump(cand, open(p, "w"))
-    assert pg.main(["--receipt", p]) == 2  # nothing comparable at all
+    assert pg.main(["--receipt", p, "--repo", _DATA]) == 2  # nothing comparable at all
     # direction 2: the same halved numbers back at the inline config
-    # gate red against the committed inline trajectory
+    # gate red against the inline trajectory
     cand["config"].update({"value_bytes": 8, "value_heap": False})
     json.dump(cand, open(p, "w"))
-    assert pg.main(["--receipt", p]) == 1
+    assert pg.main(["--receipt", p, "--repo", _DATA]) == 1
     # explicit inline fields match the field-less history exactly
-    cand2 = pg.load_receipt(os.path.join(_repo_root(),
-                                         "BENCH_r05.json"))
+    cand2 = pg.load_receipt(_R05)
     cand2.pop("_round", None)
     cand2.setdefault("config", {})
     cand2["config"].update({"value_bytes": 8, "value_dist": "fixed",
                             "value_heap": False})
     json.dump(cand2, open(p, "w"))
-    assert pg.main(["--receipt", p]) == 0
+    assert pg.main(["--receipt", p, "--repo", _DATA]) == 0
 
 
 def test_perfgate_node_count_change_is_incomparable(tmp_path, capsys):
@@ -521,23 +526,23 @@ def test_perfgate_node_count_change_is_incomparable(tmp_path, capsys):
     workload changed wholesale).  A missing ``nodes`` field means the
     pre-field machine_nr=1 bench, so 1-node receipts keep gating."""
     pg = _perfgate()
-    cand = pg.load_receipt(os.path.join(_repo_root(), "BENCH_r05.json"))
+    cand = pg.load_receipt(_R05)
     cand.pop("_round", None)
     cand["nodes"] = 6  # a post-reshard capture at the grown shape
     for k in ("value", "sustained_ops_s", "sus_mixed_ops_s"):
         cand[k] = round(cand[k] * 0.5)
     p = str(tmp_path / "resharded.json")
     json.dump(cand, open(p, "w"))
-    assert pg.main(["--receipt", p]) == 2  # nothing comparable at all
+    assert pg.main(["--receipt", p, "--repo", _DATA]) == 2  # nothing comparable at all
     # same numbers at the trajectory's own shape: a real regression
     cand["nodes"] = 1
     json.dump(cand, open(p, "w"))
-    assert pg.main(["--receipt", p]) == 1
+    assert pg.main(["--receipt", p, "--repo", _DATA]) == 1
     # and a reshard-drill receipt is not a bench receipt: exits 2
     drill = {"metric": "reshard_drill", "ok": True, "lost_acks": 0,
              "rpo_ops": 0, "nodes": 4, "target_nodes": 6}
     json.dump(drill, open(p, "w"))
-    assert pg.main(["--receipt", p]) == 2
+    assert pg.main(["--receipt", p, "--repo", _DATA]) == 2
 
 
 def test_perfgate_cache_on_never_gates_against_cache_off(tmp_path,
@@ -548,21 +553,21 @@ def test_perfgate_cache_on_never_gates_against_cache_off(tmp_path,
     trajectory, even when the number would otherwise read as a
     regression; the symmetric throughput metrics still gate."""
     pg = _perfgate()
-    cand = pg.load_receipt(os.path.join(_repo_root(), "BENCH_r05.json"))
+    cand = pg.load_receipt(_R05)
     cand.pop("_round", None)
     cand["cache"] = {"enabled": True, "slots": 65536,
                      "hit_ratio": 0.79, "hit_ratio_pred": 0.79}
     cand["sustained_ops_s"] = round(cand["sustained_ops_s"] * 0.5)
     p = str(tmp_path / "cache_on.json")
     json.dump(cand, open(p, "w"))
-    assert pg.main(["--receipt", p]) == 0  # halved sustained: skipped
+    assert pg.main(["--receipt", p, "--repo", _DATA]) == 0  # halved sustained: skipped
     res = json.loads(capsys.readouterr().out.strip().splitlines()[-1])
     assert "skipped" in res["metrics"]["sustained_ops_s"]
     # and the rule is symmetric config-matching, not a blanket skip:
     # with the cache OFF the same number is a real regression
     cand.pop("cache")
     json.dump(cand, open(p, "w"))
-    assert pg.main(["--receipt", p]) == 1
+    assert pg.main(["--receipt", p, "--repo", _DATA]) == 1
 
 
 def test_perfgate_red_on_steady_state_retraces(tmp_path, capsys):
@@ -570,18 +575,18 @@ def test_perfgate_red_on_steady_state_retraces(tmp_path, capsys):
     retrace inside a sealed window fails HARD (no noise margin) even
     with every throughput metric at baseline."""
     pg = _perfgate()
-    cand = pg.load_receipt(os.path.join(_repo_root(), "BENCH_r05.json"))
+    cand = pg.load_receipt(_R05)
     cand.pop("_round", None)
     cand["device"] = {"ledger": {"retraces": 1}}
     p = str(tmp_path / "retrace.json")
     json.dump(cand, open(p, "w"))
-    assert pg.main(["--receipt", p]) == 1
+    assert pg.main(["--receipt", p, "--repo", _DATA]) == 1
     res = json.loads(capsys.readouterr().out.strip().splitlines()[-1])
     assert not res["metrics"]["device.retraces"]["ok"]
     # zero retraces: the same receipt passes
     cand["device"] = {"ledger": {"retraces": 0}}
     json.dump(cand, open(p, "w"))
-    assert pg.main(["--receipt", p]) == 0
+    assert pg.main(["--receipt", p, "--repo", _DATA]) == 0
 
 
 def test_perfgate_device_bytes_frac_drop_flagged_and_skips_old_rounds():
@@ -697,9 +702,8 @@ def test_staged_step_obs_cost_under_two_percent(eight_devices,
     wall(True)  # warm: compiles + first-dispatch cost stay out
     # The loops are identical code either way (attribution is per
     # window, not per step), so min-of-N over interleaved pairs should
-    # be flat; retry the whole A/B on a noise spike (the same
-    # measured-retry shape bench.py uses for tunnel degradation) so a
-    # busy CI host cannot fail a claim about OBS cost.
+    # be flat; retry the whole A/B on a noise spike so a busy CI host
+    # cannot fail a claim about OBS cost.
     for attempt in range(3):
         on, off = [], []
         for _ in range(3):

@@ -68,48 +68,6 @@ def test_dsm_step_over_pallas_exchange(eight_devices):
     assert old == 50 + int(np.nonzero(rep.ok)[0][0])
 
 
-def test_multichip_tpu_lowering_smoke():
-    """Compile-smoke the COMPILED kernel form (use_barrier=True — the
-    branch the interpreter cannot reach): lower the 8-device exchange
-    for the TPU target over an AbstractMesh, exercising the full
-    Pallas->Mosaic lowering of get_barrier_semaphore, cross-device
-    semaphore signal/wait, and the posted remote copies.  Executing it
-    still requires real multi-chip hardware."""
-    import jax
-    import jax.numpy as jnp
-    from jax.sharding import AbstractMesh, NamedSharding
-    from jax.sharding import PartitionSpec as P
-
-    from sherman_tpu.parallel import transport_pallas as TP
-    if not TP.HAVE_PALLAS:
-        pytest.skip("pallas unavailable")
-
-    N, C, W = 8, 16, 8
-    try:
-        mesh = AbstractMesh((N,), ("node",))
-    except TypeError:  # JAX < 0.5 spells the shape as (name, size) pairs
-        mesh = AbstractMesh((("node", N),))
-    spec = P("node")
-
-    def step(x):
-        return TP.exchange_pallas(x, "node", N, interpret=False)
-
-    fn = jax.jit(jax.shard_map(step, mesh=mesh, in_specs=spec,
-                               out_specs=spec, check_vma=False))
-    arg = jax.ShapeDtypeStruct((N * N * C, W), jnp.int32,
-                               sharding=NamedSharding(mesh, spec))
-    try:
-        txt = fn.trace(arg).lower(lowering_platforms=("tpu",)).as_text()
-    except ValueError as e:
-        # only the known capability gap skips (JAX < 0.5 cannot lower
-        # over a device-less AbstractMesh); any other lowering error is
-        # a real regression this smoke test exists to catch
-        if "AbstractMesh" in str(e) or "_device_assignment" in str(e):
-            pytest.skip(f"AbstractMesh TPU lowering unsupported here: {e}")
-        raise
-    assert "tpu_custom_call" in txt or "mosaic" in txt.lower()
-
-
 def test_collective_id_distinct_per_shape_family():
     from sherman_tpu.parallel.transport_pallas import _collective_id
     ids = {(_collective_id(n, c, w))

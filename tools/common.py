@@ -15,15 +15,16 @@ sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
 
 def setup_platform(n_nodes: int):
-    """Pick the backend: n_nodes == 1 uses the default platform (the real
-    chip when present); n_nodes > 1 forces an n-node virtual CPU mesh (the
-    in-process multi-node backend, SURVEY.md §4's fake-transport lesson)
-    unless SHERMAN_PLATFORM overrides.  Must run before the first jax
-    device query — a devices() call initializes the backend and freezes
+    """Pick the backend: the real devices by default (the chips, one node
+    each).  Only an explicit CPU request — ``SHERMAN_PLATFORM=cpu`` or
+    ``JAX_PLATFORMS=cpu``, as the tests and CI lanes make — selects an
+    n-node virtual CPU mesh (the in-process multi-node backend, SURVEY.md
+    §4's fake-transport lesson).  Fewer devices than nodes is an error,
+    never a fall back to the CPU.  Must run before the first jax device
+    query — a devices() call initializes the backend and freezes
     XLA_FLAGS."""
-    platform = os.environ.get("SHERMAN_PLATFORM", "")
-    if n_nodes > 1 and not platform:
-        platform = "cpu"
+    platform = (os.environ.get("SHERMAN_PLATFORM")
+                or os.environ.get("JAX_PLATFORMS", ""))
     if platform == "cpu":
         flags = os.environ.get("XLA_FLAGS", "")
         if "xla_force_host_platform_device_count" not in flags:
@@ -34,35 +35,27 @@ def setup_platform(n_nodes: int):
     if platform:
         jax.config.update("jax_platforms", platform)
     devs = jax.devices()
-    assert len(devs) >= n_nodes, (
-        f"need {n_nodes} devices, have {len(devs)}")
+    if len(devs) < n_nodes:
+        raise RuntimeError(
+            f"{n_nodes} nodes need {n_nodes} devices, but "
+            f"{devs[0].platform} has {len(devs)}: run on a host with "
+            f"{n_nodes} chips, or ask for the CPU mesh explicitly "
+            "(SHERMAN_PLATFORM=cpu)")
     return jax
 
 
-def build_cluster(n_nodes: int, pages_per_node: int, batch_per_node: int,
-                  locks_per_node: int = 65_536, chunk_pages: int = 4096,
-                  exchange_impl: str = "xla"):
-    from sherman_tpu.cluster import Cluster
-    from sherman_tpu.config import DSMConfig, TreeConfig
-    from sherman_tpu.models import batched
-    from sherman_tpu.models.btree import Tree
-
-    cfg = DSMConfig(machine_nr=n_nodes, pages_per_node=pages_per_node,
-                    locks_per_node=locks_per_node,
-                    step_capacity=batch_per_node, chunk_pages=chunk_pages,
-                    exchange_impl=exchange_impl)
-    cluster = Cluster(cfg)
-    tree = Tree(cluster)
-    eng = batched.BatchedEngine(tree, batch_per_node=batch_per_node,
-                                tcfg=TreeConfig(sibling_chase_budget=1))
-    return cluster, tree, eng
+def build_cluster(*args, **kw):
+    """-> (cluster, tree, engine): the library's one construction rule
+    (imported late: the platform is picked first)."""
+    from sherman_tpu.cluster import build_engine
+    return build_engine(*args, **kw)
 
 
 def pages_for_keys(n_keys: int, fill: float = 0.75) -> int:
-    from sherman_tpu.config import LEAF_CAP
-    per_leaf = max(1, int(LEAF_CAP * fill))
-    est = int(n_keys / per_leaf * 1.10) + 8192
-    return 1 << max(12, (est - 1).bit_length())
+    """The library's sizing rule with the drivers' 2^12-page floor (their
+    CPU-mesh runs are small)."""
+    from sherman_tpu.cluster import pages_for_keys as pages
+    return pages(n_keys, fill, min_pages=1 << 12)
 
 
 class AdmissionPacer:

@@ -125,9 +125,8 @@ def _receipt_report(path: str) -> dict:
 def _live_report() -> dict:
     import jax
 
-    jax.config.update("jax_compilation_cache_dir", os.path.join(
-        os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
-        ".jax_cache"))
+    from sherman_tpu.utils.compile_cache import setup_compile_cache
+    setup_compile_cache()
 
     import common
     from sherman_tpu import native, obs

@@ -1,18 +1,21 @@
 #!/usr/bin/env python
 """Perf-regression gate over the committed BENCH_r*.json trajectory.
 
-The published throughput trajectory (BENCH_r01..r05 at the repo root)
-is the contract every perf PR must not silently regress.  This tool
+The published throughput trajectory (``BENCH_r*.json`` in ``--repo``,
+the repo root by default) is the contract every perf PR must not
+silently regress.  This tool
 parses the committed rounds plus a fresh receipt, applies NOISE-AWARE
 thresholds, and exits nonzero on regression — the CI lane
-(``scripts/obs_ci.sh``) runs it against the committed r05 receipt so
-the gate itself is pinned green on known-good data, and against a
+(``scripts/obs_ci.sh``) runs it against the synthetic trajectory in
+``tests/data/perfgate`` so the gate itself is pinned green on
+known-good data, and against a
 synthetically degraded receipt so it is pinned RED on a real loss.
 
-Noise calibration: the round-5 capture measured 33.8 M ops/s in the
-log and 32.2 M in the JSON for the SAME configuration minutes apart
-(BENCHMARKS.md row-1 annotation) — a ~5% same-build run spread through
-the access tunnel.  The default margin is ``max(--min-margin,
+Noise calibration: a pre-PR-1 round-5 capture (git history at 2d6a76f)
+measured 33.8 M ops/s in the log and 32.2 M in the JSON for the SAME
+configuration minutes apart — a ~5% same-build run spread.  The gate
+anchors on it until chip runs of this tree measure their own spread.
+The default margin is ``max(--min-margin,
 --spread-mult x max(calibrated spread, observed cross-round spread))``
 per metric: with the defaults (min 10%, mult 2.0) a -20% sustained
 loss FAILS while the r05-vs-r05 and r02-r05 cross-round wiggles (~1-7%)
@@ -120,7 +123,8 @@ gate.
 
 Usage::
 
-    python tools/perfgate.py --receipt BENCH_r05.json        # pass pin
+    python tools/perfgate.py --receipt tests/data/perfgate/BENCH_r05.json \
+        --repo tests/data/perfgate                           # pass pin
     python tools/perfgate.py --receipt fresh.json            # gate a run
     python tools/perfgate.py --receipt f.json --json         # receipt only
 

@@ -39,9 +39,8 @@ def stage_deltas():
     import jax.numpy as jnp
     from jax import lax
 
-    jax.config.update("jax_compilation_cache_dir", os.path.join(
-        os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
-        ".jax_cache"))
+    from sherman_tpu.utils.compile_cache import setup_compile_cache
+    setup_compile_cache()
 
     from sherman_tpu.ops import bits
     from sherman_tpu.workload.device_prep import (

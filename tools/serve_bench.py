@@ -689,10 +689,8 @@ def main(argv=None) -> None:
     a = ap.parse_args(argv)
 
     jax = setup_platform(1)
-    jax.config.update("jax_compilation_cache_dir", os.path.join(
-        os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
-        ".jax_cache"))
-    jax.config.update("jax_persistent_cache_min_compile_time_secs", 1.0)
+    from sherman_tpu.utils.compile_cache import setup_compile_cache
+    setup_compile_cache()
 
     out = run_crash_drill(a) if a.crash_drill else run_serve(a)
     print(json.dumps(out))
