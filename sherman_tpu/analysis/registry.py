@@ -128,6 +128,7 @@ DEFAULT_REGISTRY = Registry(
         ("sherman_tpu/workload/device_prep.py", "make_device_prep.*"),
         ("sherman_tpu/workload/device_prep.py",
          "make_ingress_step.dispatch_device"),
+        ("sherman_tpu/serve.py", "ShermanServer._take_reads"),
         ("sherman_tpu/serve.py", "ShermanServer._dispatch_reads"),
         # client-contract plane (PR 15): the dispatch-path queue pops
         # run per formed step under the admission lock — deadline
@@ -243,6 +244,15 @@ DEFAULT_REGISTRY = Registry(
         # server-side twin is covered by the ShermanServer._note_*
         # glob above
         ("sherman_tpu/replica.py", "ReplicaGroup._note_quorum"),
+        # the one span API: the front door's dispatcher opens ~10 spans
+        # a step (serve.take/prep/complete/idle and their children), so
+        # opening, closing and recording a span build nothing beyond
+        # the event tuple; the ring, the aggregates and the Chrome
+        # export allocate at PULL time.  The per-request latency split
+        # (ShermanServer._note_answer) rides the _note_* glob above.
+        ("sherman_tpu/obs/spans.py", "_Span.__enter__"),
+        ("sherman_tpu/obs/spans.py", "_Span.__exit__"),
+        ("sherman_tpu/obs/spans.py", "SpanTracer._record"),
     ],
     knob_docs=["BENCHMARKS.md"],
 )

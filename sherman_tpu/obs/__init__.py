@@ -9,10 +9,11 @@ instrumentation surface every layer reports through:
   (counters, gauges, histograms) with snapshot/delta semantics so
   drivers and tests can diff op counts around a timed region.  Hot-path
   increments are a plain attribute add — no locks, no dict lookups.
-- :mod:`sherman_tpu.obs.spans` — nested span tracing with thread-safe
-  recording and Chrome-trace-event JSON export (loadable in
-  ``chrome://tracing`` / Perfetto), absorbing the legacy
-  :class:`StepTrace` micro-tracer.
+- :mod:`sherman_tpu.obs.spans` — the one span API (``obs.span``):
+  nested, thread-safe spans that also land on the profiler trace's host
+  plane (``jax.profiler.TraceAnnotation``), per-name aggregates, and a
+  bounded ring of recent spans exported as Chrome-trace-event JSON
+  (loadable in ``chrome://tracing`` / Perfetto).
 - :mod:`sherman_tpu.obs.slo` — the SLO telemetry layer: per-op-class
   (read/insert/delete/mixed/scan) amortized latency with sliding-window
   ops/s and p50/p99/p999, fed by every batch wall (engine entry points
@@ -65,14 +66,13 @@ from sherman_tpu.obs.registry import (Counter, Gauge, Histogram,
                                       register_collector, snapshot)
 from sherman_tpu.obs.slo import (LatencyTracker, SloTracker, WindowedRate,
                                  get_slo, observe, observe_op, slo_window)
-from sherman_tpu.obs.spans import (SpanTracer, StepTrace, device_trace,
-                                   get_tracer, span)
+from sherman_tpu.obs.spans import SpanTracer, device_trace, get_tracer, span
 
 __all__ = [
     "Counter", "Gauge", "Histogram", "MetricsRegistry",
     "counter", "gauge", "histogram", "snapshot", "delta",
     "register_collector", "get_registry",
-    "SpanTracer", "StepTrace", "device_trace", "get_tracer", "span",
+    "SpanTracer", "device_trace", "get_tracer", "span",
     "dump", "obs_section", "write_snapshot_jsonl", "PeriodicExporter",
     "prometheus_text", "write_prometheus", "MetricsServer",
     "maybe_serve_http",

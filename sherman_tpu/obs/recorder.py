@@ -10,9 +10,9 @@ never per op), and dumped as a readable bundle when something breaks.
 
 Event sources (each site calls :func:`record_event`):
 
-- ``span``                      every default-tracer span close
-  (completion is per *phase*, not per request — the tracer's own
-  contract keeps this off the hot path)
+- ``span``                      every default-tracer span close but
+  the ``hot`` ones (the front door's per-step ``serve.*`` spans, which
+  a profiler trace and the tracer's own ring see)
 - ``chaos.inject``              each fired fault (kind/step/addr)
 - ``lease.revoked``             a dead holder's lock revoked
 - ``scrub.violation`` / ``scrub.quarantine``

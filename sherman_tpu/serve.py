@@ -72,7 +72,25 @@ enqueue):
   snapshot / scrape), beside admission/reject/tenant-share counters
   and the current width; the engine-side service walls still feed the
   default ``slo.`` tracker via ``obs.slo.observe`` — the controller
-  consumes both.
+  consumes both.  The dispatcher thread (``sherman-serve-dispatch``)
+  marks its work with ``obs.span`` spans, which land on a profiler
+  trace's host plane beside the device's programs: ``serve.idle`` (one
+  span for each stretch with nothing due), ``serve.take`` (the loop
+  head — admission lock, degraded check, write and scan lanes — and
+  the read step's width pick and fair-share take; a linger wait with
+  admitted work not yet due nests in it as ``serve.idle``),
+  ``serve.prep`` (the ingress dispatch: ``serve.prep.combine`` /
+  ``.cache`` / ``.router`` / ``.h2d`` and ``serve.launch``),
+  ``serve.complete`` (``serve.materialize``, ``serve.rescue``,
+  ``serve.answer``), ``serve.flush_writes`` and ``serve.flush_scans``;
+  each step span carries ``step=<k>``.  All but the rare
+  ``serve.rescue`` are ``hot`` spans: untraced they cost one TraceMe
+  check and reach neither the tracer's ring nor its aggregates.  Every
+  read future records the step it rode in (``step``), its step's
+  dispatch time (``t_dispatch``) and the time its answer was set
+  (``t_answer``); the ``serve.queue_wait_ms`` (submit -> dispatch) and
+  ``serve.service_ms`` (dispatch -> answer) histograms take one value
+  per answered read request.
 
 - **Client contract** (PR 15 — the exactly-once / deadline / audit
   plane):
@@ -364,10 +382,17 @@ class ServeFuture:
     blocks until the ack and re-raises the typed error when the
     request failed in flight (degraded write shed, deadline shed,
     dispatcher failure).  ``deduped`` marks a result re-acked from the
-    exactly-once window (the original ack, not a re-apply)."""
+    exactly-once window (the original ack, not a re-apply).
+
+    A read records the dispatcher step it rode in (``step``, the
+    ``step=`` argument of that step's ``serve.*`` spans) and that
+    step's dispatch time (``t_dispatch``); every future records when it
+    resolved (``t_answer``).  All three are ``perf_counter`` seconds
+    like ``t_submit``, ``None`` until set."""
 
     __slots__ = ("op", "tenant", "n_ops", "t_submit", "rid", "deadline",
-                 "deduped", "_ev", "_result", "_error")
+                 "deduped", "step", "t_dispatch", "t_answer", "_ev",
+                 "_result", "_error")
 
     def __init__(self, op: str, tenant: str, n_ops: int,
                  rid=None, deadline: float | None = None):
@@ -378,6 +403,9 @@ class ServeFuture:
         self.rid = rid
         self.deadline = deadline
         self.deduped = False
+        self.step = None
+        self.t_dispatch = None
+        self.t_answer = None
         self._ev = threading.Event()
         self._result = None
         self._error: BaseException | None = None
@@ -394,10 +422,12 @@ class ServeFuture:
 
     def _set(self, result) -> None:
         self._result = result
+        self.t_answer = time.perf_counter()
         self._ev.set()
 
     def _fail(self, err: BaseException) -> None:
         self._error = err
+        self.t_answer = time.perf_counter()
         self._ev.set()
 
 
@@ -659,6 +689,11 @@ class ShermanServer:
         self._cur_width = self.cfg.widths[0]
         self._completions = 0
         self._last_complete_t = 0.0
+        self._next_step = 0  # the dispatcher's read-step id (spans' step=)
+        # per-request split of a read's latency (handles made here:
+        # the hot path records plain floats)
+        self._h_queue_wait = obs.histogram("serve.queue_wait_ms")
+        self._h_service = obs.histogram("serve.service_ms")
         # receipt counters (plain adds on the hot paths — SL006)
         self.admitted_ops = 0
         self.served_ops = 0
@@ -957,7 +992,7 @@ class ShermanServer:
         if self.auditor is not None:
             self.auditor.start()
         self._thread = threading.Thread(target=self._loop,
-                                        name="sherman-serve",
+                                        name="sherman-serve-dispatch",
                                         daemon=True)
         self._thread.start()
         if self.cfg.write_lane:
@@ -1199,25 +1234,37 @@ class ShermanServer:
     def _loop(self) -> None:
         pend: deque = deque()  # in-flight read slots (two-deep pipeline)
         while True:
-            with self._lock:
-                if not self._running and (not self._draining
-                                          or self._queued_ops == 0):
-                    break
-                has_work = self._queued_ops > 0
-                if not has_work and not pend:
-                    self._cv.wait(0.002)
-                    has_work = self._queued_ops > 0
-                    if not has_work and not pend:
-                        continue
             try:
-                self._check_degraded_transition()
-                # write flushes ride the dedicated lane when enabled —
-                # the dispatcher's read loop must never stall behind a
-                # journal fsync (the PR-13 REMAINING write-path story)
-                did = False if self.cfg.write_lane \
-                    else self._maybe_flush_writes()
-                did = self._maybe_flush_scans() or did
-                slot = self._dispatch_reads()
+                with self._lock:
+                    if not self._queued_ops and not pend \
+                            and self._running:
+                        # nothing due: one ``serve.idle`` span for the
+                        # whole stretch, however many wake-ups it takes
+                        with obs.span("serve.idle", hot=True):
+                            while not self._queued_ops and self._running:
+                                self._cv.wait(0.002)
+                # the loop head, the write/scan lanes and the read take
+                # form one ``serve.take`` span: a wait for the admission
+                # lock or the GIL while clients submit lands in it
+                with obs.span("serve.take", hot=True,
+                              step=self._next_step):
+                    with self._lock:
+                        if not self._running and (
+                                not self._draining
+                                or self._queued_ops == 0):
+                            break
+                        if not self._queued_ops and not pend:
+                            continue
+                    self._check_degraded_transition()
+                    # write flushes ride the dedicated lane when enabled
+                    # — the dispatcher's read loop must never stall
+                    # behind a journal fsync (the PR-13 REMAINING
+                    # write-path story)
+                    did = False if self.cfg.write_lane \
+                        else self._maybe_flush_writes()
+                    did = self._maybe_flush_scans() or did
+                    formed = self._take_reads()
+                slot = self._dispatch_reads(*formed) if formed else None
                 if slot is not None:
                     pend.append(slot)
                     did = True
@@ -1231,7 +1278,7 @@ class ShermanServer:
                     # admitted work exists but none of it is due yet
                     # (write linger): sleep a beat instead of spinning
                     # the GIL out from under the client threads
-                    with self._lock:
+                    with self._lock, obs.span("serve.idle", hot=True):
                         self._cv.wait(0.0005)
             except BaseException as e:  # noqa: BLE001 — serving loop
                 # must survive a bad batch: the batch's futures carry
@@ -1251,7 +1298,7 @@ class ShermanServer:
                 if self._draining:
                     self._complete_read(slot)
                 else:
-                    width, reqs, handle, _t0, tok = slot
+                    width, reqs, handle, _t0, tok, _k = slot
                     self._fail_batch(reqs, StateError(
                         "server killed with the batch in flight"))
                     self._steps[width].drain(handle)
@@ -1408,9 +1455,11 @@ class ShermanServer:
                     head = q[0].fut.n_ops
             return self._queued_read_ops, head
 
-    def _dispatch_reads(self):
-        """Form one read step at the controller's width and launch it
-        (async).  Returns the in-flight slot or None."""
+    def _take_reads(self):
+        """Pick the controller's width and take one read step's requests
+        -> (step id, width, requests), or None with nothing to form."""
+        if self._queued_read_ops == 0:   # unlocked peek: nothing to form
+            return None
         backlog, head = self._read_backlog()
         if backlog == 0:
             return None
@@ -1422,24 +1471,37 @@ class ShermanServer:
         reqs = self._take(("read",), width)
         if not reqs:
             return None
-        keys = np.concatenate([r.keys for r in reqs]) \
-            if len(reqs) > 1 else reqs[0].keys
-        # auditor intent for the whole flight: a pipelined read records
-        # its events a full iteration after dispatch — the checker's
-        # cut must not close a window over it meanwhile
-        tok = self.auditor.begin_ops(
-            min(r.fut.t_submit for r in reqs)) \
-            if self.auditor is not None else None
-        t0 = time.perf_counter()
-        try:
-            handle = self._steps[width].dispatch(keys)
-        except BaseException as e:  # noqa: BLE001 — the batch's futures
-            # must carry the failure; the loop keeps serving
-            self._fail_batch(reqs, e)
-            if tok is not None:
-                self.auditor.end_ops(tok)
-            return None
-        return (width, reqs, handle, t0, tok)
+        k = self._next_step
+        self._next_step = k + 1
+        return k, width, reqs
+
+    def _dispatch_reads(self, k: int, width: int, reqs):
+        """Launch a taken read step (async).  Returns the in-flight slot
+        or None."""
+        with obs.span("serve.prep", hot=True, step=k, width=width,
+                      requests=len(reqs),
+                      keys=sum(r.fut.n_ops for r in reqs)):
+            keys = np.concatenate([r.keys for r in reqs]) \
+                if len(reqs) > 1 else reqs[0].keys
+            # auditor intent for the whole flight: a pipelined read
+            # records its events a full iteration after dispatch — the
+            # checker's cut must not close a window over it meanwhile
+            tok = self.auditor.begin_ops(
+                min(r.fut.t_submit for r in reqs)) \
+                if self.auditor is not None else None
+            t0 = time.perf_counter()
+            for r in reqs:
+                r.fut.step = k
+                r.fut.t_dispatch = t0
+            try:
+                handle = self._steps[width].dispatch(keys, step=k)
+            except BaseException as e:  # noqa: BLE001 — the batch's
+                # futures must carry the failure; the loop keeps serving
+                self._fail_batch(reqs, e)
+                if tok is not None:
+                    self.auditor.end_ops(tok)
+                return None
+        return (width, reqs, handle, t0, tok, k)
 
     def _fail_batch(self, reqs, e: BaseException) -> None:
         self.dispatch_errors += 1
@@ -1458,14 +1520,15 @@ class ShermanServer:
             raise e
 
     def _complete_read(self, slot) -> None:
-        width, reqs, handle, t0, tok = slot
+        width, reqs, handle, t0, tok, k = slot
         try:
-            self._complete_read_inner(width, reqs, handle, t0)
+            with obs.span("serve.complete", hot=True, step=k):
+                self._complete_read_inner(width, reqs, handle, t0, k)
         finally:
             if tok is not None and self.auditor is not None:
                 self.auditor.end_ops(tok)
 
-    def _complete_read_inner(self, width, reqs, handle, t0) -> None:
+    def _complete_read_inner(self, width, reqs, handle, t0, k) -> None:
         try:
             vals, found = self._steps[width].complete(handle)
         except BaseException as e:  # noqa: BLE001
@@ -1511,61 +1574,66 @@ class ShermanServer:
                 # failed batch
                 self._fail_batch(reqs, e)
                 return
-        off = 0
-        oldest = t1
-        # auditor feed: u64-register reads only (handle-bearing heap
-        # reads are outside the register model — see audit.py)
-        aud = self.auditor if self.value_heap is None else None
-        for req in reqs:
-            m = req.fut.n_ops
-            try:
-                if req.resolve_payloads:
-                    req.fut._set(self._payload_result(
-                        req, vals, found, pay, nb, vok, off, m,
-                        side=side, cache=cache))
-                else:
-                    req.fut._set((vals[off:off + m],
-                                  found[off:off + m]))
-            except BaseException as e:  # noqa: BLE001 — a raising
-                # per-request payload resolve (HeapCorruptError on a
-                # torn slab) must fail THAT future typed, not leave it
-                # (and every later request in the batch) unset forever
-                self.dispatch_errors += 1
-                FR.record_event("serve.dispatch_error", error=repr(e))
-                req.fut._fail(e if isinstance(e, ShermanError)
-                              else StateError(
-                                  f"payload resolve failed: {e!r}"))
-                if isinstance(e, (KeyboardInterrupt, SystemExit)):
-                    raise
-            # end-to-end (submit -> ack) latency — the SLO the target
-            # governs, attributed per REQUEST (the client's unit of
-            # experience) weighted by its ops
-            self.tracker.observe("read", m, t1 - req.fut.t_submit)
-            if aud is not None:
-                aud.observe_read(req.keys, vals[off:off + m],
-                                 found[off:off + m],
-                                 req.fut.t_submit, t1)
-            if req.fut.t_submit < oldest:
-                oldest = req.fut.t_submit
-            st = self._tenants[req.fut.tenant]
-            self._note_served(st, m)
-            off += m
-        # queue-vs-service attribution: formation wait of the batch's
-        # OLDEST request vs the service wall — when waiting dominates,
-        # the tail belongs to the offered load, not the width
-        qwait = max(0.0, t0 - oldest)
-        ratio = qwait / wall if wall > 0 else 0.0
-        self._qwait_ratio = 0.7 * self._qwait_ratio + 0.3 * ratio
-        self._completions += 1
-        if self._completions % 16 == 0:
-            # measured-truth override: the window p99 disposes what the
-            # wall model proposed (queue-dominated breaches excluded —
-            # see WidthController.note_window_p99)
-            w = self.tracker.window().get("read")
-            if w and w["window_ops"]:
-                self.controller.note_window_p99(
-                    w["p99_ms"],
-                    queue_dominated=self._qwait_ratio > 1.0)
+        with obs.span("serve.answer", hot=True, step=k):
+            off = 0
+            oldest = t1
+            note_wait = self._h_queue_wait.record
+            note_service = self._h_service.record
+            # auditor feed: u64-register reads only (handle-bearing heap
+            # reads are outside the register model — see audit.py)
+            aud = self.auditor if self.value_heap is None else None
+            for req in reqs:
+                m = req.fut.n_ops
+                try:
+                    if req.resolve_payloads:
+                        req.fut._set(self._payload_result(
+                            req, vals, found, pay, nb, vok, off, m,
+                            side=side, cache=cache))
+                    else:
+                        req.fut._set((vals[off:off + m],
+                                      found[off:off + m]))
+                    note_wait((t0 - req.fut.t_submit) * 1e3)
+                    note_service((req.fut.t_answer - t0) * 1e3)
+                except BaseException as e:  # noqa: BLE001 — a raising
+                    # per-request payload resolve (HeapCorruptError on a
+                    # torn slab) must fail THAT future typed, not leave it
+                    # (and every later request in the batch) unset forever
+                    self.dispatch_errors += 1
+                    FR.record_event("serve.dispatch_error", error=repr(e))
+                    req.fut._fail(e if isinstance(e, ShermanError)
+                                  else StateError(
+                                      f"payload resolve failed: {e!r}"))
+                    if isinstance(e, (KeyboardInterrupt, SystemExit)):
+                        raise
+                # end-to-end (submit -> ack) latency — the SLO the target
+                # governs, attributed per REQUEST (the client's unit of
+                # experience) weighted by its ops
+                self.tracker.observe("read", m, t1 - req.fut.t_submit)
+                if aud is not None:
+                    aud.observe_read(req.keys, vals[off:off + m],
+                                     found[off:off + m],
+                                     req.fut.t_submit, t1)
+                if req.fut.t_submit < oldest:
+                    oldest = req.fut.t_submit
+                st = self._tenants[req.fut.tenant]
+                self._note_served(st, m)
+                off += m
+            # queue-vs-service attribution: formation wait of the batch's
+            # OLDEST request vs the service wall — when waiting dominates,
+            # the tail belongs to the offered load, not the width
+            qwait = max(0.0, t0 - oldest)
+            ratio = qwait / wall if wall > 0 else 0.0
+            self._qwait_ratio = 0.7 * self._qwait_ratio + 0.3 * ratio
+            self._completions += 1
+            if self._completions % 16 == 0:
+                # measured-truth override: the window p99 disposes what the
+                # wall model proposed (queue-dominated breaches excluded —
+                # see WidthController.note_window_p99)
+                w = self.tracker.window().get("read")
+                if w and w["window_ops"]:
+                    self.controller.note_window_p99(
+                        w["p99_ms"],
+                        queue_dominated=self._qwait_ratio > 1.0)
 
     def _sidecar_hits(self, reqs, vals, found, cache):
         """Probe the leaf cache's payload sidecar for every found
@@ -1773,7 +1841,9 @@ class ShermanServer:
             min(r.fut.t_submit for r in reqs)) \
             if self.auditor is not None else None
         try:
-            return self._flush_writes(reqs)
+            with obs.span("serve.flush_writes", hot=True,
+                          requests=len(reqs)):
+                return self._flush_writes(reqs)
         finally:
             if tok is not None:
                 self.auditor.end_ops(tok)
@@ -1880,21 +1950,24 @@ class ShermanServer:
 
     def _maybe_flush_scans(self) -> bool:
         reqs = self._take(("scan",), self.cfg.widths[-1])
-        for r in reqs:
-            try:
-                res = self.value_heap.scan(r.ranges) \
-                    if (r.resolve_payloads
-                        and self.value_heap is not None) \
-                    else self.eng.range_query_many(r.ranges)
-                r.fut._set(res)
-                self.tracker.observe(
-                    "scan", r.fut.n_ops,
-                    time.perf_counter() - r.fut.t_submit)
-                self._note_served(self._tenants[r.fut.tenant],
-                                  r.fut.n_ops)
-            except BaseException as e:  # noqa: BLE001
-                self._fail_batch([r], e)
-        return bool(reqs)
+        if not reqs:
+            return False
+        with obs.span("serve.flush_scans", hot=True, requests=len(reqs)):
+            for r in reqs:
+                try:
+                    res = self.value_heap.scan(r.ranges) \
+                        if (r.resolve_payloads
+                            and self.value_heap is not None) \
+                        else self.eng.range_query_many(r.ranges)
+                    r.fut._set(res)
+                    self.tracker.observe(
+                        "scan", r.fut.n_ops,
+                        time.perf_counter() - r.fut.t_submit)
+                    self._note_served(self._tenants[r.fut.tenant],
+                                      r.fut.n_ops)
+                except BaseException as e:  # noqa: BLE001
+                    self._fail_batch([r], e)
+        return True
 
     # -- telemetry -----------------------------------------------------------
 

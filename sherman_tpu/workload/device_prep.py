@@ -575,19 +575,22 @@ def make_staged_step(eng, *, n_keys: int, theta: float, salt: int,
     def prep_core(tpair, rtable, rkey, step_idx):
         # per-node, per-step independent stream (counter-based PRNG):
         # fold the step counter and the node index into the key
-        node = lax.axis_index(AXIS) if N > 1 else jnp.uint32(0)
-        k = jax.random.fold_in(rkey, step_idx * np.uint32(N)
-                               + node.astype(jnp.uint32))
-        w = jax.random.bits(k, (2, batch), dtype=jnp.uint32)
-        rank = gen_ranks(tpair, w)
-        khi_u, klo_u = _keys_of_ranks(rank, salt_hi, salt_lo)
+        with jax.named_scope("sample"):
+            node = lax.axis_index(AXIS) if N > 1 else jnp.uint32(0)
+            k = jax.random.fold_in(rkey, step_idx * np.uint32(N)
+                                   + node.astype(jnp.uint32))
+            w = jax.random.bits(k, (2, batch), dtype=jnp.uint32)
+            rank = gen_ranks(tpair, w)
+            khi_u, klo_u = _keys_of_ranks(rank, salt_hi, salt_lo)
         # sort-based unique (request combining): clients are served in
         # key-sorted order (see module docstring), so no index payload
         # and no inverse-permutation scatter are needed
-        skhi, sklo, ukhi, uklo, seg, n_uniq = _sort_combine(
-            khi_u, klo_u, dev_b)
-        active = lax.iota(jnp.int32, dev_b) < n_uniq
-        start = _router_probe(rtable, ukhi, uklo, shift, nb)
+        with jax.named_scope("combine"):
+            skhi, sklo, ukhi, uklo, seg, n_uniq = _sort_combine(
+                khi_u, klo_u, dev_b)
+            active = lax.iota(jnp.int32, dev_b) < n_uniq
+        with jax.named_scope("router_probe"):
+            start = _router_probe(rtable, ukhi, uklo, shift, nb)
         return skhi, sklo, ukhi, uklo, start, active, seg, n_uniq
 
     def serve_fanout(pool, counters, ukhi, uklo, start, active, seg):
@@ -599,14 +602,15 @@ def make_staged_step(eng, *, n_keys: int, theta: float, salt: int,
         counters, done, found, vhi, vlo = search_routed_spmd(
             pool, counters, i32(ukhi), i32(uklo), root, active, start,
             cfg=cfg, iters=iters)
-        ans = jnp.stack([found.astype(jnp.int32), vhi, vlo,
-                         jnp.zeros_like(vhi)], axis=-1)     # [U_loc, 4]
-        if N > 1:
-            node = lax.axis_index(AXIS)
-            ans = transport.gather_rows(ans, AXIS)
-            seg = seg + node.astype(jnp.int32) * dev_b
-        safe = jnp.clip(seg, 0, ans.shape[0] - 1)
-        out = jnp.take_along_axis(ans, safe[:, None], axis=0)
+        with jax.named_scope("fanout"):
+            ans = jnp.stack([found.astype(jnp.int32), vhi, vlo,
+                             jnp.zeros_like(vhi)], axis=-1)  # [U_loc, 4]
+            if N > 1:
+                node = lax.axis_index(AXIS)
+                ans = transport.gather_rows(ans, AXIS)
+                seg = seg + node.astype(jnp.int32) * dev_b
+            safe = jnp.clip(seg, 0, ans.shape[0] - 1)
+            out = jnp.take_along_axis(ans, safe[:, None], axis=0)
         return counters, out[:, 0] != 0, out[:, 1], out[:, 2]
 
     def verify_core(rcarry, skhi, sklo, found, vhi, vlo, n_uniq):
@@ -1166,37 +1170,40 @@ def make_staged_mixed_step(eng, *, n_keys: int, theta: float, salt: int,
     assert R >= dev_rb and Wc >= dev_wb, "dev caps cannot exceed class sizes"
 
     def prep(tpair, rtable, rkey, step_idx):
-        node = lax.axis_index(AXIS) if N > 1 else jnp.uint32(0)
-        k = jax.random.fold_in(rkey, step_idx * np.uint32(N)
-                               + node.astype(jnp.uint32))
-        w = jax.random.bits(k, (2, batch), dtype=jnp.uint32)
-        rank = gen_ranks(tpair, w)
-        khi_u, klo_u = _keys_of_ranks(rank, salt_hi, salt_lo)
+        with jax.named_scope("sample"):
+            node = lax.axis_index(AXIS) if N > 1 else jnp.uint32(0)
+            k = jax.random.fold_in(rkey, step_idx * np.uint32(N)
+                                   + node.astype(jnp.uint32))
+            w = jax.random.bits(k, (2, batch), dtype=jnp.uint32)
+            rank = gen_ranks(tpair, w)
+            khi_u, klo_u = _keys_of_ranks(rank, salt_hi, salt_lo)
         # slots [0, R) are read clients, [R, batch) write clients; each
         # class combines independently (same pipeline as the read-only
         # staged step)
-        rskhi, rsklo, rukhi, ruklo, rseg, r_nu = _sort_combine(
-            khi_u[:R], klo_u[:R], dev_rb)
-        wskhi, wsklo, wukhi, wuklo, wseg, w_nu = _sort_combine(
-            khi_u[R:], klo_u[R:], dev_wb)
-        # the [reads | writes] row block mixed_step_spmd serves
-        akhi = jnp.concatenate([rukhi, wukhi])
-        aklo = jnp.concatenate([ruklo, wuklo])
-        act_r = jnp.concatenate([
-            lax.iota(jnp.int32, dev_rb) < r_nu,
-            jnp.zeros((dev_wb,), bool)])
-        act_w = jnp.concatenate([
-            jnp.zeros((dev_rb,), bool),
-            lax.iota(jnp.int32, dev_wb) < w_nu])
-        # write value = key ^ check_xor ^ (step + 1): identical across a
-        # step's duplicates (combining sound), step-decodable for the
-        # read-side linearization check
-        stamp = step_idx + np.uint32(1)
-        vhi = jnp.concatenate([jnp.zeros((dev_rb,), jnp.uint32),
-                               wukhi ^ cx_hi])
-        vlo = jnp.concatenate([jnp.zeros((dev_rb,), jnp.uint32),
-                               wuklo ^ cx_lo ^ stamp])
-        start = _router_probe(rtable, akhi, aklo, shift, nb)
+        with jax.named_scope("combine"):
+            rskhi, rsklo, rukhi, ruklo, rseg, r_nu = _sort_combine(
+                khi_u[:R], klo_u[:R], dev_rb)
+            wskhi, wsklo, wukhi, wuklo, wseg, w_nu = _sort_combine(
+                khi_u[R:], klo_u[R:], dev_wb)
+            # the [reads | writes] row block mixed_step_spmd serves
+            akhi = jnp.concatenate([rukhi, wukhi])
+            aklo = jnp.concatenate([ruklo, wuklo])
+            act_r = jnp.concatenate([
+                lax.iota(jnp.int32, dev_rb) < r_nu,
+                jnp.zeros((dev_wb,), bool)])
+            act_w = jnp.concatenate([
+                jnp.zeros((dev_rb,), bool),
+                lax.iota(jnp.int32, dev_wb) < w_nu])
+            # write value = key ^ check_xor ^ (step + 1): identical
+            # across a step's duplicates (combining sound),
+            # step-decodable for the read-side linearization check
+            stamp = step_idx + np.uint32(1)
+            vhi = jnp.concatenate([jnp.zeros((dev_rb,), jnp.uint32),
+                                   wukhi ^ cx_hi])
+            vlo = jnp.concatenate([jnp.zeros((dev_rb,), jnp.uint32),
+                                   wuklo ^ cx_lo ^ stamp])
+        with jax.named_scope("router_probe"):
+            start = _router_probe(rtable, akhi, aklo, shift, nb)
         return (step_idx + np.uint32(1), akhi, aklo, vhi, vlo, act_r,
                 act_w, start, rskhi, rsklo, rseg, r_nu[None],
                 wseg, w_nu[None])
@@ -1212,19 +1219,20 @@ def make_staged_mixed_step(eng, *, n_keys: int, theta: float, salt: int,
             pool, locks, counters, i32(akhi), i32(aklo), i32(vhi),
             i32(vlo), root, act_r, act_w, start, cfg=cfg, iters=iters,
             write_lo=dev_rb, update_only=True)
-        ans = jnp.stack([found.astype(jnp.int32), rvh, rvl,
-                         jnp.zeros_like(rvh)], axis=-1)[:dev_rb]
-        stat_w = status[dev_rb:]
-        if N > 1:
-            node = lax.axis_index(AXIS)
-            ans = transport.gather_rows(ans, AXIS)
-            stat_w = transport.gather_rows(stat_w, AXIS)
-            rseg = rseg + node.astype(jnp.int32) * dev_rb
-            wseg = wseg + node.astype(jnp.int32) * dev_wb
-        out = jnp.take_along_axis(
-            ans, jnp.clip(rseg, 0, ans.shape[0] - 1)[:, None], axis=0)
-        st_cli = jnp.take_along_axis(
-            stat_w, jnp.clip(wseg, 0, stat_w.shape[0] - 1), axis=0)
+        with jax.named_scope("fanout"):
+            ans = jnp.stack([found.astype(jnp.int32), rvh, rvl,
+                             jnp.zeros_like(rvh)], axis=-1)[:dev_rb]
+            stat_w = status[dev_rb:]
+            if N > 1:
+                node = lax.axis_index(AXIS)
+                ans = transport.gather_rows(ans, AXIS)
+                stat_w = transport.gather_rows(stat_w, AXIS)
+                rseg = rseg + node.astype(jnp.int32) * dev_rb
+                wseg = wseg + node.astype(jnp.int32) * dev_wb
+            out = jnp.take_along_axis(
+                ans, jnp.clip(rseg, 0, ans.shape[0] - 1)[:, None], axis=0)
+            st_cli = jnp.take_along_axis(
+                stat_w, jnp.clip(wseg, 0, stat_w.shape[0] - 1), axis=0)
         return pool, counters, out, st_cli
 
     def verify_mixed_core(rcarry, rskhi, rsklo, out, st_cli, r_nu, w_nu):
@@ -1623,8 +1631,10 @@ def make_ingress_step(eng, *, width: int, leaf_cache=None,
     fn = eng._get_search_fanout(iters)
     root = np.int32(eng.tree._root_addr)
     # prep-phase attribution (PR 17): per-dispatch host wall of the
-    # request plane, split host-vs-device — histogram handles created
-    # here so dispatch (SL001-hot) only records plain floats
+    # request plane, split host-vs-device — histogram and counter
+    # handles created here so dispatch (SL001-hot) only records plain
+    # numbers.  The spans below run on the caller's (the front door's
+    # dispatcher) thread; ``step`` is the caller's step id.
     import time as _time
     from sherman_tpu import obs as _obs
     _h_prep = _obs.histogram(
@@ -1632,60 +1642,78 @@ def make_ingress_step(eng, *, width: int, leaf_cache=None,
         else "prep.host_ms")
     _obs.gauge("prep.impl_device").set(
         1.0 if prep_impl == "device" else 0.0)
+    _c_rescues = _obs.counter("serve.rescues")
+    _c_rescued_keys = _obs.counter("serve.rescued_keys")
 
-    def dispatch(keys):
+    def dispatch(keys, step: int = -1):
         t0p = _time.perf_counter()
         n = keys.shape[0]
-        uk, inv = np.unique(keys, return_inverse=True)
-        U = uk.shape[0]
-        kh, kl = bits.keys_to_pairs(uk)
-        khi = np.zeros(width, kh.dtype)
-        klo = np.zeros(width, kl.dtype)
-        khi[:U] = kh
-        klo[:U] = kl
-        active = np.zeros(width, bool)
-        active[:U] = True
+        with _obs.span("serve.prep.combine", hot=True, step=step):
+            uk, inv = np.unique(keys, return_inverse=True)
+            U = uk.shape[0]
+            kh, kl = bits.keys_to_pairs(uk)
+            khi = np.zeros(width, kh.dtype)
+            klo = np.zeros(width, kl.dtype)
+            khi[:U] = kh
+            klo[:U] = kl
+            active = np.zeros(width, bool)
+            active[:U] = True
+            inv_p = np.zeros(width, np.int32)
+            inv_p[:n] = inv.astype(np.int32)
         chit = cvhi = cvlo = None
         if leaf_cache is not None:
             # admission sketch sees the RAW (duplicated) client stream —
             # frequency ranking needs the multiplicities — then the
             # probe drops pool-validated hits out of the device batch
-            leaf_cache.observe(keys)
-            chit, cvhi, cvlo = leaf_cache.probe(khi, klo, active)
-            active = active & ~chit
-        start = router.host_start(khi, klo)
-        inv_p = np.zeros(width, np.int32)
-        inv_p[:n] = inv.astype(np.int32)
-        args = (eng._shard(khi), eng._shard(klo), root,
-                eng._shard(active), eng._shard(start),
-                eng._shard(inv_p))
-        with eng._step_mutex:  # launch-only, the engine step contract
+            with _obs.span("serve.prep.cache", hot=True, step=step):
+                leaf_cache.observe(keys)
+                chit, cvhi, cvlo = leaf_cache.probe(khi, klo, active)
+                active = active & ~chit
+        with _obs.span("serve.prep.router", hot=True, step=step):
+            start = router.host_start(khi, klo)
+        with _obs.span("serve.prep.h2d", hot=True, step=step):
+            args = (eng._shard(khi), eng._shard(klo), root,
+                    eng._shard(active), eng._shard(start),
+                    eng._shard(inv_p))
+        # launch-only, the engine step contract
+        with _obs.span("serve.launch", hot=True, step=step), eng._step_mutex:
             eng.dsm.counters, done, found, vhi, vlo = fn(
                 eng.dsm.pool, eng.dsm.counters, *args)
         _h_prep.record((_time.perf_counter() - t0p) * 1e3)
-        return (n, U, uk, inv, done, found, vhi, vlo, chit, cvhi, cvlo)
+        return (n, U, uk, inv, done, found, vhi, vlo, chit, cvhi, cvlo,
+                step)
+
+    def rescue(uk, step):
+        """Straggler rescue (stale seeds / height growth): the engine's
+        root-descent path answers the whole unique set ``uk`` (search()
+        owns retries + SLO attribution)."""
+        with _obs.span("serve.rescue", step=step, keys=uk.shape[0]):
+            _c_rescues.inc()
+            _c_rescued_keys.inc(uk.shape[0])
+            return eng.search(uk)
 
     def complete(handle):
-        n, U, uk, inv, done, found, vhi, vlo, chit, cvhi, cvlo = handle
-        done, found, vhi, vlo = eng._unshard(done, found, vhi, vlo)
-        done_u = np.asarray(done[:U])
-        if chit is not None:
-            done_u = done_u | chit[:U]
-        if not bool(done_u.all()):
-            # straggler rescue (stale seeds / height growth): the
-            # engine's root-descent path answers the whole unique set,
-            # host fan-out (search() owns retries + SLO attribution)
-            vals_u, found_u = eng.search(uk)
+        n, U, uk, inv, done, found, vhi, vlo, chit, cvhi, cvlo, k = handle
+        with _obs.span("serve.materialize", hot=True, step=k):
+            done, found, vhi, vlo = eng._unshard(done, found, vhi, vlo)
+            done_u = np.asarray(done[:U])
+            if chit is not None:
+                done_u = done_u | chit[:U]
+            stragglers = not bool(done_u.all())
+            if not stragglers:
+                vals = np.array(bits.pairs_to_keys(vhi[:n], vlo[:n]))
+                fnd = np.array(found[:n])
+                if chit is not None and chit[:U].any():
+                    # cache hits' device rows were inactive — overwrite
+                    # their client rows through the same inverse map the
+                    # fan-out used
+                    ch = chit[:U][inv][:n]
+                    fnd[ch] = True
+                    vals[ch] = np.asarray(bits.pairs_to_keys(
+                        cvhi[:U], cvlo[:U]))[inv][:n][ch]
+        if stragglers:
+            vals_u, found_u = rescue(uk, k)
             return vals_u[inv][:n], found_u[inv][:n]
-        vals = np.array(bits.pairs_to_keys(vhi[:n], vlo[:n]))
-        fnd = np.array(found[:n])
-        if chit is not None and chit[:U].any():
-            # cache hits' device rows were inactive — overwrite their
-            # client rows through the same inverse map the fan-out used
-            ch = chit[:U][inv][:n]
-            fnd[ch] = True
-            vals[ch] = np.asarray(bits.pairs_to_keys(
-                cvhi[:U], cvlo[:U]))[inv][:n][ch]
         return vals, fnd
 
     def step(keys):
@@ -1729,7 +1757,7 @@ def make_ingress_step(eng, *, width: int, leaf_cache=None,
                 _rt["ver"] = ver
             return _rt["rtable"], _rt["shift"]
 
-        def dispatch_device(keys):
+        def dispatch_device(keys, step: int = -1):
             """Device-prep twin of ``dispatch`` (same SL001 hot-path
             contract: launch-only, no host syncs of device data): the
             host's only per-batch work is the pair split + sentinel
@@ -1739,39 +1767,51 @@ def make_ingress_step(eng, *, width: int, leaf_cache=None,
             touching the host."""
             t0p = _time.perf_counter()
             n = keys.shape[0]
-            kh, kl = bits.keys_to_pairs(keys)
-            khi_raw = np.full(width, -1, np.int32)   # KEY_POS_INF pair
-            klo_raw = np.full(width, -1, np.int32)
-            khi_raw[:n] = kh
-            klo_raw[:n] = kl
-            rtable, shift = _router_state()
-            khi, klo, active, start, inv_p, n_uniq = prep_fn(
-                jax.device_put(khi_raw), jax.device_put(klo_raw),
-                jax.device_put(np.int32(n)), rtable, shift)
-            with eng._step_mutex:  # launch-only, the engine step contract
-                eng.dsm.counters, done, found, vhi, vlo = fn(
-                    eng.dsm.pool, eng.dsm.counters, khi, klo, root,
-                    active, start, inv_p)
+            with _obs.span("serve.prep.combine", hot=True, step=step):
+                kh, kl = bits.keys_to_pairs(keys)
+                khi_raw = np.full(width, -1, np.int32)  # KEY_POS_INF pair
+                klo_raw = np.full(width, -1, np.int32)
+                khi_raw[:n] = kh
+                klo_raw[:n] = kl
+            with _obs.span("serve.prep.router", hot=True, step=step):
+                rtable, shift = _router_state()
+            with _obs.span("serve.prep.h2d", hot=True, step=step):
+                args = (jax.device_put(khi_raw), jax.device_put(klo_raw),
+                        jax.device_put(np.int32(n)))
+            with _obs.span("serve.launch", hot=True, step=step):
+                khi, klo, active, start, inv_p, n_uniq = prep_fn(
+                    *args, rtable, shift)
+                # launch-only, the engine step contract
+                with eng._step_mutex:
+                    eng.dsm.counters, done, found, vhi, vlo = fn(
+                        eng.dsm.pool, eng.dsm.counters, khi, klo, root,
+                        active, start, inv_p)
             _h_prep.record((_time.perf_counter() - t0p) * 1e3)
             return (n, n_uniq, (khi, klo), inv_p, done, found, vhi, vlo,
-                    None, None, None)
+                    None, None, None, step)
 
         def complete_device(handle):
             """Completion half (materializes by design): the unique
             count syncs here, and the straggler rescue lazily
             materializes the unique set + inverse map only when a
             descent actually overran."""
-            n, n_uniq, ukpair, inv_p, done, found, vhi, vlo, *_ = handle
-            done, found, vhi, vlo = eng._unshard(done, found, vhi, vlo)
-            U = int(np.asarray(n_uniq))
-            if not bool(np.asarray(done[:U]).all()):
-                ukhi, uklo = eng._unshard(*ukpair)
-                uk = bits.pairs_to_keys(ukhi[:U], uklo[:U])
-                inv = np.asarray(eng._unshard(inv_p))[:n]
-                vals_u, found_u = eng.search(uk)
+            n, n_uniq, ukpair, inv_p, done, found, vhi, vlo, *_, k = handle
+            with _obs.span("serve.materialize", hot=True, step=k):
+                done, found, vhi, vlo = eng._unshard(done, found, vhi,
+                                                     vlo)
+                U = int(np.asarray(n_uniq))
+                stragglers = not bool(np.asarray(done[:U]).all())
+                if stragglers:
+                    ukhi, uklo = eng._unshard(*ukpair)
+                    uk = bits.pairs_to_keys(ukhi[:U], uklo[:U])
+                    inv = np.asarray(eng._unshard(inv_p))[:n]
+                else:
+                    vals = np.array(bits.pairs_to_keys(vhi[:n], vlo[:n]))
+                    fnd = np.array(found[:n])
+            if stragglers:
+                vals_u, found_u = rescue(uk, k)
                 return vals_u[inv], found_u[inv]
-            vals = np.array(bits.pairs_to_keys(vhi[:n], vlo[:n]))
-            return vals, np.array(found[:n])
+            return vals, fnd
 
         dispatch, complete = dispatch_device, complete_device
 

@@ -8,7 +8,7 @@ import pytest
 
 from sherman_tpu import obs
 from sherman_tpu.obs.registry import MetricsRegistry, delta
-from sherman_tpu.obs.spans import SpanTracer, StepTrace
+from sherman_tpu.obs.spans import SpanTracer
 
 
 # -- registry ----------------------------------------------------------------
@@ -162,20 +162,6 @@ def test_collector_raises_mid_storm_isolated():
 
 # -- spans -------------------------------------------------------------------
 
-def test_legacy_steptrace_api_still_works():
-    # the exact pre-obs surface, importable from the old module path
-    from sherman_tpu.utils.trace import StepTrace as LegacyStepTrace
-    assert LegacyStepTrace is StepTrace
-    tr = LegacyStepTrace()
-    with tr.span("descend"):
-        pass
-    tr.record("descend", 0.25)
-    s = tr.summary()
-    assert s["descend"]["n"] == 2
-    assert s["descend"]["total_s"] >= 0.25
-    assert "descend" in tr.report()
-
-
 def test_nested_spans_and_summary():
     tr = SpanTracer()
     with tr.span("outer"):
@@ -289,6 +275,86 @@ def test_event_cap_keeps_aggregates():
     assert tr.summary()["s"]["n"] == 10  # aggregate sees everything
     assert len(tr.chrome_trace()["traceEvents"]) == 3
     assert tr.dropped == 7
+
+
+def test_span_ring_keeps_the_newest_events():
+    tr = SpanTracer(max_events=4)
+    for i in range(10):
+        with tr.span("s", i=i):
+            pass
+    evs = tr.chrome_trace()["traceEvents"]
+    assert [e["args"]["i"] for e in evs] == [6, 7, 8, 9]
+    assert tr.chrome_trace()["otherData"]["dropped_events"] == 6
+    tr.reset()
+    assert tr.dropped == 0 and tr.chrome_trace()["traceEvents"] == []
+
+
+def test_span_ring_accounts_every_span_under_thread_churn():
+    """More recording threads than cores, a short switch interval and a
+    small ring: every span is either in the ring or counted dropped,
+    and the aggregate sees all of them."""
+    import os
+    import sys
+    tr = SpanTracer(max_events=64)
+    n_threads = 2 * (os.cpu_count() or 4)
+    per = 300
+
+    def worker():
+        for _ in range(per):
+            with tr.span("w"):
+                pass
+
+    old = sys.getswitchinterval()
+    sys.setswitchinterval(1e-6)
+    try:
+        ts = [threading.Thread(target=worker) for _ in range(n_threads)]
+        for t in ts:
+            t.start()
+        for t in ts:
+            t.join(60)
+    finally:
+        sys.setswitchinterval(old)
+    assert not any(t.is_alive() for t in ts)
+    total = n_threads * per
+    assert tr.summary()["w"]["n"] == total
+    assert len(tr.chrome_trace()["traceEvents"]) == 64
+    assert tr.dropped == total - 64
+
+
+def test_span_lands_on_the_profiler_host_plane(tmp_path):
+    """``obs.span`` opens a TraceAnnotation: under a profiler trace the
+    span is an event of the same name, with its arguments as stats, on
+    the host plane's line of the thread that ran it."""
+    import glob
+
+    import jax
+    import jax.numpy as jnp
+    from jax.profiler import ProfileData
+
+    tr = SpanTracer()
+    with jax.profiler.trace(str(tmp_path)):
+        with tr.span("test.outer", step=7):
+            with tr.span("test.inner"):
+                jax.block_until_ready(jnp.arange(8) * 2)
+    path = glob.glob(str(tmp_path / "**" / "*.xplane.pb"), recursive=True)
+    prof = ProfileData.from_file(path[0])
+    found = {}
+    for plane in prof.planes:
+        if not plane.name.startswith("/host:"):
+            continue
+        for line in plane.lines:
+            for ev in line.events:
+                if ev.name.startswith("test."):
+                    found[ev.name] = (line.name, ev.start_ns,
+                                      ev.duration_ns, dict(ev.stats))
+    assert set(found) == {"test.outer", "test.inner"}
+    outer, inner = found["test.outer"], found["test.inner"]
+    assert outer[3] == {"step": 7}
+    assert outer[0] == inner[0]                     # one thread's line
+    assert outer[1] <= inner[1] and \
+        inner[1] + inner[2] <= outer[1] + outer[2]  # nested in time
+    # the span's own record is unchanged by the trace
+    assert tr.summary()["test.outer"]["n"] == 1
 
 
 # -- export ------------------------------------------------------------------

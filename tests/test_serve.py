@@ -279,6 +279,97 @@ def test_serve_reads_writes_scans(eight_devices):
         srv.submit("read", keys[:4])
 
 
+def test_serve_request_records_and_dispatcher_spans(eight_devices, tmp_path):
+    """Every answered read records its step, dispatch and answer times;
+    ``serve.queue_wait_ms`` and ``serve.service_ms`` take one value per
+    answered read request.  The dispatcher's spans are hot: untraced, an
+    idle or a serving dispatcher records none; under a profiler trace
+    they carry step ids, and an idle stretch is one ``serve.idle`` span
+    however many wake-ups it takes."""
+    import jax
+    from sherman_tpu import obs
+    tree, eng, keys, vals = make()
+    hq = obs.histogram("serve.queue_wait_ms")
+    hs = obs.histogram("serve.service_ms")
+    tr = obs.get_tracer()
+
+    def serve_counts():
+        return {k: v["n"] for k, v in tr.summary().items()
+                if k.startswith("serve.")}
+
+    with serving(eng, keys, vals) as srv:
+        untraced = serve_counts()
+        srv.submit("read", keys[:8]).result(timeout=60)
+        time.sleep(0.05)                     # ~25 idle wake-ups
+        assert serve_counts() == untraced
+        n0q, n0s = hq.count, hs.count
+        before = tr.summary()
+        with jax.profiler.trace(str(tmp_path)):
+            rng = np.random.default_rng(5)
+            futs = [srv.submit("read", keys[rng.integers(0, keys.size, 50)],
+                               tenant=f"t{i % 2}") for i in range(20)]
+            for f in futs:
+                assert f.result(timeout=60)[1].all()
+            time.sleep(0.2)                  # ~100 idle wake-ups
+        assert srv._thread.name == "sherman-serve-dispatch"
+    assert hq.count - n0q == hs.count - n0s == len(futs)
+    for f in futs:
+        assert isinstance(f.step, int) and f.step >= 0
+        assert f.t_submit <= f.t_dispatch <= f.t_answer
+    after = tr.summary()
+    steps = {f.step for f in futs}
+
+    def grew(name):
+        return after.get(name, {"n": 0})["n"] - \
+            before.get(name, {"n": 0})["n"]
+
+    for name in ("serve.take", "serve.prep", "serve.prep.combine",
+                 "serve.prep.router", "serve.prep.h2d", "serve.launch",
+                 "serve.complete", "serve.materialize", "serve.answer"):
+        assert grew(name) >= len(steps), name
+    assert grew("serve.complete") == len(steps)
+    assert 1 <= grew("serve.idle") <= 2 * len(steps) + 2
+    # a step's spans share the step id its requests recorded
+    evs = tr.chrome_trace()["traceEvents"]
+    prep_steps = {e["args"]["step"] for e in evs
+                  if e["name"] == "serve.prep"}
+    assert steps <= prep_steps
+    prep = [e for e in evs if e["name"] == "serve.prep"
+            and e["args"]["step"] == futs[0].step][0]
+    assert prep["args"]["width"] in (128, 512)
+    assert prep["args"]["requests"] >= 1 and prep["args"]["keys"] >= 50
+
+
+def test_stale_router_step_counts_a_rescue(eight_devices):
+    """Router seeds far left of a key's leaf overrun the descent's
+    sibling-chase budget: the step's stragglers go through the engine's
+    root descent, counted in ``serve.rescues`` / ``serve.rescued_keys``
+    (registry counters, so in ``obs.snapshot()``), and every answer is
+    still right."""
+    from sherman_tpu import obs
+    tree, eng, keys, vals = make()
+    step = make_ingress_step(eng, width=256)
+    kreq = keys[-200:]
+    c, ck = obs.counter("serve.rescues"), obs.counter("serve.rescued_keys")
+    r0, k0 = c.value, ck.value
+    got, found = step(kreq)
+    assert c.value == r0      # fresh seeds: no rescue
+    router = eng.router
+    table = router.table_np.copy()
+    router.table_np[:] = table[0]     # every seed at the first leaf
+    try:
+        got, found = step(kreq)
+    finally:
+        router.table_np[:] = table
+    assert found.all()
+    np.testing.assert_array_equal(got, kreq * np.uint64(7))
+    assert c.value == r0 + 1
+    assert ck.value == k0 + 200
+    snap = obs.snapshot()
+    assert snap["serve.rescues"] == c.value
+    assert snap["serve.rescued_keys"] == ck.value
+
+
 def test_serve_validates_requests(eight_devices):
     tree, eng, keys, vals = make()
     with serving(eng, keys, vals) as srv:

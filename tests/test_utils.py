@@ -55,27 +55,11 @@ def test_debug_error_to_stderr(capsys):
     assert "boom x" in capsys.readouterr().err
 
 
-def test_step_trace_spans_and_report():
-    import time as _time
-
-    from sherman_tpu.utils.trace import StepTrace
-    tr = StepTrace()
-    for _ in range(3):
-        with tr.span("phase_a"):
-            _time.sleep(0.001)
-    tr.record("phase_b", 0.5)
-    s = tr.summary()
-    assert s["phase_a"]["n"] == 3 and s["phase_a"]["total_s"] >= 0.003
-    assert s["phase_b"] == {"n": 1, "total_s": 0.5, "mean_ms": 500.0}
-    rep = tr.report()
-    assert "phase_a" in rep and "phase_b" in rep
-
-
 def test_device_trace_writes_profile(tmp_path):
     import jax
     import jax.numpy as jnp
 
-    from sherman_tpu.utils.trace import device_trace
+    from sherman_tpu.obs import device_trace
     with device_trace(str(tmp_path)):
         jax.block_until_ready(jnp.arange(8) * 2)
     import os
