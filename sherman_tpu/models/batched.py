@@ -1814,20 +1814,11 @@ class BatchedEngine:
         fn = self._search_cache.get(("fanout", iters))
         if fn is None:
             spec, rep = self._spec, self._rep
-            N = self.cfg.machine_nr
+            body = self._search_fanout_body(iters, done_lane=False)
 
             def kernel(pool, counters, khi, klo, root, active, start, inv):
-                counters, done, found, vhi, vlo = search_routed_spmd(
-                    pool, counters, khi, klo, root, active, start,
-                    cfg=self.cfg, iters=iters)
-                with jax.named_scope("fanout"):
-                    ans = jnp.stack([found.astype(jnp.int32), vhi, vlo,
-                                     jnp.zeros_like(vhi)],
-                                    axis=-1)                    # [U_loc, 4]
-                    if N > 1:
-                        ans = transport.gather_rows(ans, AXIS)  # [U, 4]
-                    safe = jnp.clip(inv, 0, ans.shape[0] - 1)
-                    out = jnp.take_along_axis(ans, safe[:, None], axis=0)
+                counters, done, out = body(pool, counters, khi, klo, root,
+                                           active, start, inv)
                 return (counters, done, out[:, 0].astype(bool),
                         out[:, 1], out[:, 2])
 
@@ -1839,6 +1830,62 @@ class BatchedEngine:
                 "engine.search_fanout",
                 jax.jit(sm, donate_argnums=C.donate_argnums(1)))
             self._search_cache[("fanout", iters)] = fn
+        return fn
+
+    def _search_fanout_body(self, iters: int, *, done_lane: bool):
+        """The routed descent plus the in-step fan-out shared by both
+        fan-out entries: ``(counters, done, out)`` with ``out`` the
+        [B_client, 4] answer table (found, vhi, vlo, lane 3).  Lane 3 is
+        zero, or with ``done_lane`` the unique row's ``done`` flag, so a
+        packed caller reads every answer from the one table."""
+        N = self.cfg.machine_nr
+
+        def body(pool, counters, khi, klo, root, active, start, inv):
+            counters, done, found, vhi, vlo = search_routed_spmd(
+                pool, counters, khi, klo, root, active, start,
+                cfg=self.cfg, iters=iters)
+            with jax.named_scope("fanout"):
+                ans = jnp.stack([found.astype(jnp.int32), vhi, vlo,
+                                 done.astype(jnp.int32) if done_lane
+                                 else jnp.zeros_like(vhi)],
+                                axis=-1)                        # [U_loc, 4]
+                if N > 1:
+                    ans = transport.gather_rows(ans, AXIS)      # [U, 4]
+                safe = jnp.clip(inv, 0, ans.shape[0] - 1)
+                out = jnp.take_along_axis(ans, safe[:, None], axis=0)
+            return counters, done, out
+
+        return body
+
+    def _get_search_fanout_packed(self, iters: int):
+        """The fan-out search at a packed host boundary (the serving
+        front door's ingress step): one ``packed`` [B_client, 5] int32
+        input of (khi, klo, active, start, inv) columns and one
+        [B_client, 4] answer table out (found, vhi, vlo, done), so a
+        step moves its batch in one host->device put and its answers in
+        one device->host copy.  Same kernel body as
+        :meth:`_get_search_fanout`; the traced function keeps the name
+        ``kernel`` so the profiler names both modules ``jit_kernel``."""
+        fn = self._search_cache.get(("fanout_packed", iters))
+        if fn is None:
+            spec, rep = self._spec, self._rep
+            body = self._search_fanout_body(iters, done_lane=True)
+
+            def kernel(pool, counters, packed, root):
+                khi, klo, active, start, inv = (packed[:, i]
+                                                for i in range(5))
+                counters, _, out = body(pool, counters, khi, klo, root,
+                                        active != 0, start, inv)
+                return counters, out
+
+            sm = jax.shard_map(
+                kernel, mesh=self.dsm.mesh,
+                in_specs=(spec, spec, spec, rep),
+                out_specs=(spec, spec), check_vma=False)
+            fn = DEV.wrap_program(
+                "engine.search_fanout_packed",
+                jax.jit(sm, donate_argnums=C.donate_argnums(1)))
+            self._search_cache[("fanout_packed", iters)] = fn
         return fn
 
     def search_combined(self, keys) -> tuple[np.ndarray, np.ndarray]:

@@ -28,8 +28,9 @@ enqueue):
   :class:`WidthController`, and dispatched through
   :func:`~sherman_tpu.workload.device_prep.make_ingress_step`: the
   host-fed twin of the ``fusion="pipelined"`` staged substrate, whose
-  serve is the SAME compiled program object the staged loops and the
-  host-staged throughput phase run.  With ``fusion="pipelined"``
+  serve runs the staged loops' kernel body through a packed-boundary
+  entry (one host->device put of the batch, one device->host copy of
+  the answers, started at launch).  With ``fusion="pipelined"``
   (default) ONE batch stays in flight: batch k's host prep + dispatch
   overlaps batch k-1's device serve, the two-deep discipline applied
   to external traffic; ``"aligned"`` completes each batch before the
@@ -85,7 +86,11 @@ enqueue):
   ``serve.answer``), ``serve.flush_writes`` and ``serve.flush_scans``;
   each step span carries ``step=<k>``.  All but the rare
   ``serve.rescue`` are ``hot`` spans: untraced they cost one TraceMe
-  check and reach neither the tracer's ring nor its aggregates.  Every
+  check and reach neither the tracer's ring nor its aggregates.
+  Registry counters ``serve.h2d_puts`` and ``serve.d2h_gets`` count the
+  ingress steps' host<->device transfers (one of each a step), and
+  ``serve.answer_copy_ready`` the steps whose device work was done
+  when their completion began.  Every
   read future records the step it rode in (``step``), its step's
   dispatch time (``t_dispatch``) and the time its answer was set
   (``t_answer``); the ``serve.queue_wait_ms`` (submit -> dispatch) and

@@ -1562,13 +1562,15 @@ def make_ingress_step(eng, *, width: int, leaf_cache=None,
     (the bench's synthetic zipf open loop); a front door serves batches
     that arrive from OUTSIDE.  This factory is the host-fed twin: client
     key batches of ONE fixed compiled ``width`` are combined, probed and
-    dispatched through the SAME serve program OBJECT the staged loops
-    and the host-staged throughput phase run
-    (``BatchedEngine._get_search_fanout`` — so the CI program-identity
-    pin and the compile-ledger label extend to the front door), with the
-    per-request answer fan-out on device via the unique-inverse map,
-    exactly like ``search_combined`` but at the CALLER's width instead
-    of the engine's fixed ``machine_nr * B``.  Fixed width is the whole
+    dispatched through the same serve kernel body the staged loops and
+    the host-staged throughput phase run, entered at a packed host
+    boundary (``BatchedEngine._get_search_fanout_packed``, ledger label
+    ``engine.search_fanout_packed``): the step's batch goes up as one
+    [width, 5] int32 put, and its answers come back as one [width, 4]
+    table whose host copy starts at launch.  The per-request answer
+    fan-out runs on device via the unique-inverse map, exactly like
+    ``search_combined`` but at the CALLER's width instead of the
+    engine's fixed ``machine_nr * B``.  Fixed width is the whole
     point: the adaptive batcher picks a step width from a pre-warmed
     ladder, and every ladder rung is one compiled shape — the sealed
     serving loop stays zero-retrace by construction.
@@ -1628,8 +1630,19 @@ def make_ingress_step(eng, *, width: int, leaf_cache=None,
         # round-trip the knob exists to remove
         prep_impl = "host"
     iters = eng._iters()
-    fn = eng._get_search_fanout(iters)
-    root = np.int32(eng.tree._root_addr)
+    fn = eng._get_search_fanout_packed(iters)
+    # the serve's root argument is device-resident: put here, and again
+    # only when the tree's root moves — never a host value per call
+    _root = {"addr": None, "dev": None}
+
+    def _root_dev():
+        addr = eng.tree._root_addr
+        if addr != _root["addr"]:
+            _root["dev"] = _rep_put(eng.dsm, np.int32(addr))
+            _root["addr"] = addr
+        return _root["dev"]
+
+    _root_dev()
     # prep-phase attribution (PR 17): per-dispatch host wall of the
     # request plane, split host-vs-device — histogram and counter
     # handles created here so dispatch (SL001-hot) only records plain
@@ -1644,6 +1657,12 @@ def make_ingress_step(eng, *, width: int, leaf_cache=None,
         1.0 if prep_impl == "device" else 0.0)
     _c_rescues = _obs.counter("serve.rescues")
     _c_rescued_keys = _obs.counter("serve.rescued_keys")
+    # the packed boundary at work: transfers per ingress step (one put,
+    # one get), and steps whose device work was done when completion
+    # began (so the answer copy started at launch had it to copy)
+    _c_puts = _obs.counter("serve.h2d_puts")
+    _c_gets = _obs.counter("serve.d2h_gets")
+    _c_ready = _obs.counter("serve.answer_copy_ready")
 
     def dispatch(keys, step: int = -1):
         t0p = _time.perf_counter()
@@ -1651,15 +1670,13 @@ def make_ingress_step(eng, *, width: int, leaf_cache=None,
         with _obs.span("serve.prep.combine", hot=True, step=step):
             uk, inv = np.unique(keys, return_inverse=True)
             U = uk.shape[0]
-            kh, kl = bits.keys_to_pairs(uk)
-            khi = np.zeros(width, kh.dtype)
-            klo = np.zeros(width, kl.dtype)
-            khi[:U] = kh
-            klo[:U] = kl
-            active = np.zeros(width, bool)
-            active[:U] = True
-            inv_p = np.zeros(width, np.int32)
-            inv_p[:n] = inv.astype(np.int32)
+            # a fresh buffer a step: the put may read it after dispatch
+            # returns (an asynchronous or zero-copy transfer)
+            packed = np.zeros((width, 5), np.int32)
+            khi, klo, active, start, inv_p = packed.T   # column views
+            khi[:U], klo[:U] = bits.keys_to_pairs(uk)
+            active[:U] = 1
+            inv_p[:n] = inv
         chit = cvhi = cvlo = None
         if leaf_cache is not None:
             # admission sketch sees the RAW (duplicated) client stream —
@@ -1667,21 +1684,22 @@ def make_ingress_step(eng, *, width: int, leaf_cache=None,
             # probe drops pool-validated hits out of the device batch
             with _obs.span("serve.prep.cache", hot=True, step=step):
                 leaf_cache.observe(keys)
-                chit, cvhi, cvlo = leaf_cache.probe(khi, klo, active)
-                active = active & ~chit
+                chit, cvhi, cvlo = leaf_cache.probe(khi, klo, active != 0)
+                active[chit] = 0
         with _obs.span("serve.prep.router", hot=True, step=step):
-            start = router.host_start(khi, klo)
+            start[:] = router.host_start(khi, klo)
         with _obs.span("serve.prep.h2d", hot=True, step=step):
-            args = (eng._shard(khi), eng._shard(klo), root,
-                    eng._shard(active), eng._shard(start),
-                    eng._shard(inv_p))
-        # launch-only, the engine step contract
-        with _obs.span("serve.launch", hot=True, step=step), eng._step_mutex:
-            eng.dsm.counters, done, found, vhi, vlo = fn(
-                eng.dsm.pool, eng.dsm.counters, *args)
+            packed = eng._shard(packed)
+            _c_puts.inc()
+        # launch-only, the engine step contract; the answers' host copy
+        # starts here and runs while the next step is prepared
+        with _obs.span("serve.launch", hot=True, step=step):
+            with eng._step_mutex:
+                eng.dsm.counters, ans = fn(eng.dsm.pool, eng.dsm.counters,
+                                           packed, _root_dev())
+            ans.copy_to_host_async()
         _h_prep.record((_time.perf_counter() - t0p) * 1e3)
-        return (n, U, uk, inv, done, found, vhi, vlo, chit, cvhi, cvlo,
-                step)
+        return n, U, uk, inv, ans, chit, cvhi, cvlo, step
 
     def rescue(uk, step):
         """Straggler rescue (stale seeds / height growth): the engine's
@@ -1693,27 +1711,31 @@ def make_ingress_step(eng, *, width: int, leaf_cache=None,
             return eng.search(uk)
 
     def complete(handle):
-        n, U, uk, inv, done, found, vhi, vlo, chit, cvhi, cvlo, k = handle
+        n, U, uk, inv, ans, chit, cvhi, cvlo, k = handle
         with _obs.span("serve.materialize", hot=True, step=k):
-            done, found, vhi, vlo = eng._unshard(done, found, vhi, vlo)
-            done_u = np.asarray(done[:U])
-            if chit is not None:
-                done_u = done_u | chit[:U]
-            stragglers = not bool(done_u.all())
+            _c_ready.inc(int(ans.is_ready()))
+            ans = eng._unshard(ans)
+            _c_gets.inc()
+            # lane 3 is each client row's unique row's done flag, and
+            # every unique row has a client row: all done iff all rows
+            done = ans[:n, 3] != 0
+            ch = None if chit is None else chit[:U][inv]  # client rows
+            if ch is not None:
+                done = done | ch
+            stragglers = not bool(done.all())
             if not stragglers:
-                vals = np.array(bits.pairs_to_keys(vhi[:n], vlo[:n]))
-                fnd = np.array(found[:n])
-                if chit is not None and chit[:U].any():
+                vals = bits.pairs_to_keys(ans[:n, 1], ans[:n, 2])
+                fnd = ans[:n, 0] != 0
+                if ch is not None and ch.any():
                     # cache hits' device rows were inactive — overwrite
                     # their client rows through the same inverse map the
                     # fan-out used
-                    ch = chit[:U][inv][:n]
                     fnd[ch] = True
-                    vals[ch] = np.asarray(bits.pairs_to_keys(
-                        cvhi[:U], cvlo[:U]))[inv][:n][ch]
+                    vals[ch] = bits.pairs_to_keys(
+                        cvhi[:U], cvlo[:U])[inv][ch]
         if stragglers:
             vals_u, found_u = rescue(uk, k)
-            return vals_u[inv][:n], found_u[inv][:n]
+            return vals_u[inv], found_u[inv]
         return vals, fnd
 
     def step(keys):
@@ -1728,17 +1750,21 @@ def make_ingress_step(eng, *, width: int, leaf_cache=None,
         the straggler rescue — a draining or crashing server must not
         launch fresh root descents (``eng.search`` compiles programs,
         takes the step mutex, and can raise through a degraded
-        engine).  The in-flight step's device buffers are blocked on
-        and released; nothing is returned — the caller has already
-        failed or resolved the slot's futures."""
-        _n, _U, _uk, _inv, done, found, vhi, vlo, *_ = handle
-        eng._unshard(done, found, vhi, vlo)
+        engine).  The in-flight step's first serve output (the answer
+        table; the device twin's done flags) is blocked on, which the
+        whole serve finishes with; nothing is returned — the caller has
+        already failed or resolved the slot's futures."""
+        eng._unshard(handle[4])
+        _c_gets.inc()
 
-    prep_fn = None
+    programs = {"serve_fanout": fn}
     if prep_impl == "device":
         import jax
 
+        # the device-resident prep outputs feed the unpacked entry
+        ufn = eng._get_search_fanout(iters)
         prep_fn, _upload = make_device_prep(eng, width=width)
+        programs = {"serve_fanout": ufn, "device_prep": prep_fn}
         # router-table snapshot versioned by the split/grow counters:
         # plain Python ints, so staleness detection costs two compares
         # per dispatch and the re-upload happens only when the table
@@ -1783,9 +1809,9 @@ def make_ingress_step(eng, *, width: int, leaf_cache=None,
                     *args, rtable, shift)
                 # launch-only, the engine step contract
                 with eng._step_mutex:
-                    eng.dsm.counters, done, found, vhi, vlo = fn(
-                        eng.dsm.pool, eng.dsm.counters, khi, klo, root,
-                        active, start, inv_p)
+                    eng.dsm.counters, done, found, vhi, vlo = ufn(
+                        eng.dsm.pool, eng.dsm.counters, khi, klo,
+                        _root_dev(), active, start, inv_p)
             _h_prep.record((_time.perf_counter() - t0p) * 1e3)
             return (n, n_uniq, (khi, klo), inv_p, done, found, vhi, vlo,
                     None, None, None, step)
@@ -1864,9 +1890,6 @@ def make_ingress_step(eng, *, width: int, leaf_cache=None,
     step.cache = leaf_cache is not None
     step.prep_impl = prep_impl
     step.prep_profile = prep_profile
-    step.programs = {"serve_fanout": fn}
-    step.phase_labels = {"serve_fanout": fn.label}
-    if prep_fn is not None:
-        step.programs["device_prep"] = prep_fn
-        step.phase_labels["device_prep"] = prep_fn.label
+    step.programs = programs
+    step.phase_labels = {name: prog.label for name, prog in programs.items()}
     return step

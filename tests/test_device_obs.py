@@ -444,3 +444,19 @@ def test_staged_phase_labels_cover_programs(eight_devices):
     # every wrapped program keeps its identity through the wrapper
     assert step.programs["serve_fanout"] is eng._get_search_fanout(
         eng._iters())
+    # the front door's packed entry: a program of its own in the ledger,
+    # whose HLO module carries the serve's name (``jit_kernel``), the
+    # name the benchmark finds the serve's device time by
+    import jax
+    serve = eng._get_search_fanout(eng._iters())
+    packed = eng._get_search_fanout_packed(eng._iters())
+    assert packed is not serve
+    assert packed is eng._get_search_fanout_packed(eng._iters())
+    assert packed.label == "engine.search_fanout_packed"
+    module = "jit_" + serve.unwrapped.__name__
+    assert module == "jit_kernel"
+    arg = jax.ShapeDtypeStruct((batch, 5), np.int32,
+                               sharding=eng.dsm.shard)
+    text = packed.lower(eng.dsm.pool, eng.dsm.counters, arg,
+                        np.int32(0)).as_text()
+    assert text.startswith(f"module @{module} ")

@@ -36,8 +36,8 @@ sys.path.insert(0, os.path.join(
     os.path.dirname(os.path.dirname(os.path.abspath(__file__))), "tools"))
 
 
-def make(n=3000, B=256, pages=2048, cap=1024, step=3):
-    cfg = DSMConfig(machine_nr=1, pages_per_node=pages,
+def make(n=3000, B=256, pages=2048, cap=1024, step=3, nodes=1):
+    cfg = DSMConfig(machine_nr=nodes, pages_per_node=pages,
                     locks_per_node=512, step_capacity=cap,
                     chunk_pages=32)
     cluster = Cluster(cfg)
@@ -235,6 +235,170 @@ def test_ingress_step_validates_width(eight_devices):
     eng2 = batched.BatchedEngine(tree, batch_per_node=64)
     with pytest.raises(ConfigError):
         make_ingress_step(eng2, width=128)  # no router attached
+
+
+def unpacked_ingress(eng, width, keys, leaf_cache=None):
+    """The ingress step at an unpacked boundary, the reference the
+    packed step must match bit for bit: five puts and the root scalar
+    into ``_get_search_fanout``, four arrays back, the same combine,
+    cache merge and straggler rescue."""
+    from sherman_tpu.ops import bits
+    n = keys.shape[0]
+    uk, inv = np.unique(keys, return_inverse=True)
+    U = uk.shape[0]
+    khi = np.zeros(width, np.int32)
+    klo = np.zeros(width, np.int32)
+    khi[:U], klo[:U] = bits.keys_to_pairs(uk)
+    active = np.zeros(width, bool)
+    active[:U] = True
+    inv_p = np.zeros(width, np.int32)
+    inv_p[:n] = inv
+    chit = None
+    if leaf_cache is not None:
+        chit, cvhi, cvlo = leaf_cache.probe(khi, klo, active)
+        active &= ~chit
+    start = eng.router.host_start(khi, klo)
+    fn = eng._get_search_fanout(eng._iters())
+    eng.dsm.counters, done, found, vhi, vlo = fn(
+        eng.dsm.pool, eng.dsm.counters, eng._shard(khi), eng._shard(klo),
+        np.int32(eng.tree._root_addr), eng._shard(active),
+        eng._shard(start), eng._shard(inv_p))
+    done, found, vhi, vlo = eng._unshard(done, found, vhi, vlo)
+    done_u = done[:U] if chit is None else done[:U] | chit[:U]
+    if not done_u.all():
+        v, f = eng.search(uk)
+        return v[inv], f[inv]
+    vals = bits.pairs_to_keys(vhi[:n], vlo[:n])
+    fnd = np.array(found[:n])
+    if chit is not None:
+        ch = chit[:U][inv]
+        fnd[ch] = True
+        vals[ch] = bits.pairs_to_keys(cvhi[:U], cvlo[:U])[inv][ch]
+    return vals, fnd
+
+
+def ingress_batches(keys, width):
+    """One key, a partial batch and a full one; the wider two with
+    duplicates, two absent keys, and cached keys (``keys[:200]``)."""
+    rng = np.random.default_rng(width)
+    dup = np.concatenate([keys[rng.integers(0, 200, width // 4)],
+                          keys[rng.integers(0, keys.size, width)]])
+    miss = np.asarray([7, 11], np.uint64)  # absent (keys start at 100)
+    return {"one": keys[-1:],
+            "partial": np.concatenate([dup[:width // 3], miss]),
+            "full": np.concatenate([miss, dup[:width - 2]])}
+
+
+@pytest.mark.parametrize("cached", (False, True), ids=("nocache", "cache"))
+@pytest.mark.parametrize("width", (256, 512))
+def test_packed_ingress_bit_identical(eight_devices, width, cached):
+    """The packed ingress answers every batch — one key, partial, full
+    width; duplicates, misses, and with stale router seeds a forced
+    straggler rescue — exactly as the unpacked serve does, and as
+    ``search_combined`` does, with the leaf cache off and on."""
+    from sherman_tpu import obs
+    tree, eng, keys, vals = make()
+    lc = None
+    if cached:
+        lc = eng.attach_leaf_cache(slots=1024)
+        lc.fill(keys[:200])
+    step = make_ingress_step(eng, width=width, leaf_cache=lc)
+    rescues = obs.counter("serve.rescues")
+    table = eng.router.table_np.copy()
+    try:
+        for stale in (False, True):
+            if stale:
+                eng.router.table_np[:] = table[0]  # every seed far left
+            r0 = rescues.value
+            for name, kreq in ingress_batches(keys, width).items():
+                got, found = step(kreq)
+                want = unpacked_ingress(eng, width, kreq, lc)
+                np.testing.assert_array_equal(got, want[0], err_msg=name)
+                np.testing.assert_array_equal(found, want[1], err_msg=name)
+                got_e, found_e = eng.search_combined(kreq)
+                np.testing.assert_array_equal(found, found_e, err_msg=name)
+                np.testing.assert_array_equal(got[found], got_e[found_e])
+                absent = np.isin(kreq, np.asarray([7, 11], np.uint64))
+                assert (found == ~absent).all(), name
+            assert rescues.value - r0 == (3 if stale else 0)
+    finally:
+        eng.router.table_np[:] = table
+    if cached:
+        assert lc.hits > 0
+        eng.detach_leaf_cache()
+
+
+def test_packed_ingress_one_put_no_implicit_transfer(eight_devices,
+                                                     monkeypatch):
+    """``dispatch`` moves its batch in one explicit put and nothing
+    implicitly (it runs under ``transfer_guard("disallow")``); the root
+    it hands the serve is device-resident, put again, explicitly, only
+    when the tree's root moves.  Each step counts one put and one get."""
+    import jax
+    from sherman_tpu import obs
+    from sherman_tpu.workload import device_prep
+    tree, eng, keys, vals = make()
+    step = make_ingress_step(eng, width=256)
+    kreq = keys[:100]
+    step(kreq)                     # compiles outside the guard
+    puts, roots = [], []
+    shard, rep_put = eng._shard, device_prep._rep_put
+    monkeypatch.setattr(eng, "_shard",
+                        lambda x: puts.append(x.shape) or shard(x))
+    monkeypatch.setattr(device_prep, "_rep_put",
+                        lambda dsm, x: roots.append(int(x))
+                        or rep_put(dsm, x))
+    names = ("serve.h2d_puts", "serve.d2h_gets", "serve.answer_copy_ready")
+    c0 = {k: obs.counter(k).value for k in names}
+    for moved in (False, False, True):
+        if moved:
+            monkeypatch.setattr(tree, "_root_addr", tree._root_addr + 1)
+        with jax.transfer_guard("disallow"):
+            h = step.dispatch(kreq)
+        got, found = step.complete(h)
+        assert found.all()
+        np.testing.assert_array_equal(got, kreq * np.uint64(7))
+    assert puts == [(256, 5)] * 3
+    assert roots == [tree._root_addr]      # the moved root, once
+    d = {k: obs.counter(k).value - c0[k] for k in names}
+    assert d["serve.h2d_puts"] == d["serve.d2h_gets"] == 3
+    assert 0 <= d["serve.answer_copy_ready"] <= 3
+    h = step.dispatch(kreq)
+    step.drain(h)
+    assert obs.counter("serve.d2h_gets").value - c0["serve.d2h_gets"] == 4
+
+
+def test_packed_ingress_four_nodes(eight_devices):
+    """On four nodes the packed [W, 5] batch and the [W, 4] answer
+    table shard on the batch axis as the separate arrays did, and the
+    answers equal the unpacked serve's and ``search_combined``'s."""
+    from jax.sharding import PartitionSpec
+    from sherman_tpu.parallel.mesh import AXIS
+    tree, eng, keys, vals = make(B=64, pages=1024, nodes=4)
+    width = 256
+    step = make_ingress_step(eng, width=width)
+    kreq = ingress_batches(keys, width)["full"]
+    puts, shard = [], eng._shard
+    eng._shard = lambda x: puts.append(shard(x)) or puts[-1]
+    try:
+        h = step.dispatch(kreq)
+    finally:
+        del eng._shard
+    (packed,) = puts
+    blocks = [(i * 64, (i + 1) * 64) for i in range(4)]
+    for arr, cols in ((packed, 5), (h[4], 4)):     # the batch, the answers
+        assert arr.shape == (width, cols)
+        assert arr.sharding.spec == PartitionSpec(AXIS)
+        assert sorted((s.index[0].start, s.index[0].stop)
+                      for s in arr.addressable_shards) == blocks
+    got, found = step.complete(h)
+    want = unpacked_ingress(eng, width, kreq)
+    np.testing.assert_array_equal(got, want[0])
+    np.testing.assert_array_equal(found, want[1])
+    got_e, found_e = eng.search_combined(kreq)
+    np.testing.assert_array_equal(found, found_e)
+    np.testing.assert_array_equal(got[found], got_e[found_e])
+    assert found.sum() == width - 2
 
 
 # -- serving basics -----------------------------------------------------------
