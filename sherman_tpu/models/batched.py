@@ -264,11 +264,15 @@ def _routed_resolve(pool, counters, khi, klo, active, start, *, iters: int,
 
     Multi-node meshes run the SAME shape per node shard: pages come
     through the bucket-routed read exchange (``D.read_pages_spmd``) —
-    round 1 at the full step capacity, the straggler loop at an
-    S-capacity exchange so straggler cost scales with miss count, not
+    round 1 with buckets sized from the B rows it carries (leaf seeds
+    spread over the nodes, ``D.spread_capacity``), the straggler loop at
+    an S-capacity exchange so straggler cost scales with miss count, not
     batch width (the reference's cache-hit path is O(1) reads per op at
     any cluster size, ``IndexCache.h:134-184``) — and the loop exits on
-    a psum'd pending count so every node leaves together.
+    a psum'd pending count so every node leaves together.  A round-1 row
+    whose bucket was full stays not-done and rides the loop; the
+    ``CNT_XCHG_OVERFLOW`` slot counts those rows, ``CNT_XCHG_REMOTE``
+    the round-1 and loop rows whose page lives on another node.
     """
     B = khi.shape[0]
     P = pool.shape[0]
@@ -291,7 +295,13 @@ def _routed_resolve(pool, counters, khi, klo, active, start, *, iters: int,
         def read(addrs, act, loop: bool):
             return D.read_pages_spmd(
                 pool, addrs, cfg=loop_cfg if loop else cfg,
-                axis_name=axis_name, active=act)
+                axis_name=axis_name, active=act, spread=not loop)
+
+        me = lax.axis_index(axis_name)
+
+        def remote(addrs, act):
+            return jnp.sum((act & (bits.addr_node(addrs) != me))
+                           .astype(jnp.uint32))
 
     def advance(pg, ok, kh, kl):
         lvl = layout.h_level(pg)
@@ -321,6 +331,12 @@ def _routed_resolve(pool, counters, khi, klo, active, start, *, iters: int,
         at_leaf = ok & (layout.h_level(pg) == 0) & ~chase
         f, vh, vl, _ = layout.leaf_find_key(pg, khi, klo)
         sib1 = layout.h_sibling(pg)
+    if N > 1:
+        # a seed is a page address, so an active row the exchange did
+        # not serve is one whose bucket was full
+        counters = counters.at[D.CNT_XCHG_OVERFLOW].add(
+            jnp.sum((active & ~ok).astype(jnp.uint32)))
+        counters = counters.at[D.CNT_XCHG_REMOTE].add(remote(start, active))
     hit = active & at_leaf
     done = ~active | at_leaf
     found = hit & f
@@ -353,8 +369,10 @@ def _routed_resolve(pool, counters, khi, klo, active, start, *, iters: int,
         return (it < max_rounds) & (pend > 0)
 
     def body(st):
-        it, s_done, s_addr, s_f, s_vh, s_vl, loop_reads, _ = st
+        it, s_done, s_addr, s_f, s_vh, s_vl, loop_reads, *xr, _ = st
         loop_reads = loop_reads + jnp.sum((~s_done).astype(jnp.uint32))
+        if N > 1:
+            xr = [xr[0] + remote(s_addr, ~s_done)]
         if fused:
             nxt, leafb, _, ok, f, vh, vl = pallas_page.descent_round(
                 pool, s_addr, s_kh, s_kl, ~s_done)
@@ -370,12 +388,17 @@ def _routed_resolve(pool, counters, khi, klo, active, start, *, iters: int,
         s_done = s_done | fin
         s_addr = jnp.where(ok & ~at_leaf, nxt, s_addr)
         return (it + 1, s_done, s_addr, s_f, s_vh, s_vl, loop_reads,
-                pend_of(s_done))
+                *xr, pend_of(s_done))
 
-    (_, s_done, s_addr, s_f, s_vh, s_vl, loop_reads, _) = lax.while_loop(
+    # the loop's remote-row count rides the carry on multi-node meshes
+    xr0 = (jnp.uint32(0),) if N > 1 else ()
+    (_, s_done, s_addr, s_f, s_vh, s_vl, loop_reads, *xr,
+     _) = lax.while_loop(
         cond, body,
-        (1, s_done, s_addr, s_f, s_vh, s_vl, jnp.uint32(0),
+        (1, s_done, s_addr, s_f, s_vh, s_vl, jnp.uint32(0), *xr0,
          pend_of(s_done)))
+    if N > 1:
+        counters = counters.at[D.CNT_XCHG_REMOTE].add(xr[0])
 
     # single scatter of the compacted results back to [B]
     res = valid & s_done
@@ -1797,7 +1820,7 @@ class BatchedEngine:
         _slo_observe("read", n, t_slo)
         return bits.pairs_to_keys(vhi[:n], vlo[:n]), found[:n]
 
-    def _get_search_fanout(self, iters: int):
+    def _get_search_fanout(self, iters: int, *, local: bool = False):
         """Search over the unique-key set + packed IN-STEP fan-out of
         every client request's answer.
 
@@ -1808,13 +1831,20 @@ class BatchedEngine:
         is then fully earned on device (nothing deferred to the host).
         Multi-node: the fan-out runs AFTER the reply exchange — each node
         all-gathers the [U, 4] answer table once, then its client slots
-        take locally (``inv`` holds GLOBAL unique indices).  jit
-        re-specializes per (unique-width, client-width) shape pair.
+        take locally (``inv`` holds GLOBAL unique indices).  ``local``:
+        every client's unique row lies on the client's own node (the
+        device-staged loops combine per node), so a multi-node fan-out
+        takes from the node's own [U_loc, 4] table at ``inv - node *
+        U_loc``, with no all-gather; on one node it is the same program.
+        jit re-specializes per (unique-width, client-width) shape pair.
         """
-        fn = self._search_cache.get(("fanout", iters))
+        local = local and self.cfg.machine_nr > 1
+        key = ("fanout_local" if local else "fanout", iters)
+        fn = self._search_cache.get(key)
         if fn is None:
             spec, rep = self._spec, self._rep
-            body = self._search_fanout_body(iters, done_lane=False)
+            body = self._search_fanout_body(iters, done_lane=False,
+                                            local=local)
 
             def kernel(pool, counters, khi, klo, root, active, start, inv):
                 counters, done, out = body(pool, counters, khi, klo, root,
@@ -1827,17 +1857,20 @@ class BatchedEngine:
                 in_specs=(spec, spec, spec, spec, rep, spec, spec, spec),
                 out_specs=(spec, spec, spec, spec, spec), check_vma=False)
             fn = DEV.wrap_program(
-                "engine.search_fanout",
+                "engine.search_fanout_local" if local
+                else "engine.search_fanout",
                 jax.jit(sm, donate_argnums=C.donate_argnums(1)))
-            self._search_cache[("fanout", iters)] = fn
+            self._search_cache[key] = fn
         return fn
 
-    def _search_fanout_body(self, iters: int, *, done_lane: bool):
+    def _search_fanout_body(self, iters: int, *, done_lane: bool,
+                            local: bool = False):
         """The routed descent plus the in-step fan-out shared by both
         fan-out entries: ``(counters, done, out)`` with ``out`` the
         [B_client, 4] answer table (found, vhi, vlo, lane 3).  Lane 3 is
         zero, or with ``done_lane`` the unique row's ``done`` flag, so a
-        packed caller reads every answer from the one table."""
+        packed caller reads every answer from the one table.  ``local``:
+        see :meth:`_get_search_fanout`."""
         N = self.cfg.machine_nr
 
         def body(pool, counters, khi, klo, root, active, start, inv):
@@ -1849,7 +1882,9 @@ class BatchedEngine:
                                  done.astype(jnp.int32) if done_lane
                                  else jnp.zeros_like(vhi)],
                                 axis=-1)                        # [U_loc, 4]
-                if N > 1:
+                if N > 1 and local:
+                    inv = inv - lax.axis_index(AXIS) * ans.shape[0]
+                elif N > 1:
                     ans = transport.gather_rows(ans, AXIS)      # [U, 4]
                 safe = jnp.clip(inv, 0, ans.shape[0] - 1)
                 out = jnp.take_along_axis(ans, safe[:, None], axis=0)

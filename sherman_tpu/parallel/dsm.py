@@ -84,7 +84,12 @@ CNT_WW_OPS = 6
 # materializes them at PULL time like every other collector.
 CNT_COMBINE_GROUPS = 7
 CNT_COMBINE_SAVED = 8
-N_COUNTERS = 10  # slot 9 spare
+# Routed read exchange (multi-node only, fed by the routed descent):
+# rows whose page lives on another node, and round-1 rows whose
+# destination bucket was full (answered by the straggler loop instead).
+CNT_XCHG_REMOTE = 9
+CNT_XCHG_OVERFLOW = 10
+N_COUNTERS = 11
 
 # Host-side step counter (device op counts ride the sharded counters
 # array and surface via the registry's "dsm" collector; this one counts
@@ -278,13 +283,28 @@ def dsm_step_spmd(pool, locks, counters, reqs, *, cfg: DSMConfig,
     return pool, locks, counters, replies
 
 
+def spread_capacity(rows: int, n_nodes: int, cap: int) -> int:
+    """Rows per destination bucket of a read exchange whose ``rows``
+    spread evenly over ``n_nodes``: 2 % over an even share, rounded up
+    to 8,192, plus 16,384 rows (bench.py's headroom rule, as the
+    benchmark sizes the unique-row cap), at most ``rows`` and ``cap``."""
+    share = -(-int(rows / n_nodes * 1.02) // 8192) * 8192 + 16_384
+    return min(rows, cap, share)
+
+
 def read_pages_spmd(pool, addrs, *, cfg: DSMConfig, axis_name: str = AXIS,
-                    active=None):
+                    active=None, spread: bool = False):
     """Lightweight read-only exchange: fetch pages for a batch of addrs.
 
     The hot-loop primitive for batched tree descent — avoids shipping write
     payloads: requests are 1 word each; only replies carry pages.
     Returns (pages [R, 256], ok [R]).
+
+    Multi-node, each destination bucket holds ``cfg.step_capacity`` rows,
+    or with ``spread`` (the rows' pages lie spread over the nodes, as
+    leaf seeds do) :func:`spread_capacity` of the call's own R rows, so
+    the owner's gather and the reply are ~R pages, not N x capacity.  A
+    row whose bucket is full comes back not-ok, for the caller to retry.
 
     ``cfg.gather_impl`` selects the page-fetch engine: "xla" (default)
     is the native gather; "pallas" routes the owner-side page reads
@@ -305,20 +325,26 @@ def read_pages_spmd(pool, addrs, *, cfg: DSMConfig, axis_name: str = AXIS,
         ok = active & (page >= 0) & (page < P)
         pages = pool[jnp.clip(page, 0, P - 1)]
         return jnp.where(ok[:, None], pages, 0), ok
-    dest = bits.addr_node(addrs)
+    if spread:
+        C = spread_capacity(addrs.shape[0], N, C)
     xch = functools.partial(transport.exchange, axis_name=axis_name,
                             impl=cfg.exchange_impl)
-    bucket_idx, routed = transport.bucketize(dest, active, N, C)
-    out = transport.scatter_to_buckets(bits.addr_page(addrs), bucket_idx, N * C)
-    inc = xch(out)
-    if pallas_page.use_pallas(cfg):
-        data = pallas_page.gather_pages(pool, inc)
-    else:
-        data = pool[jnp.clip(inc, 0, P - 1)]
-    rep = xch({"data": data, "okb": (inc >= 0) & (inc < P)})
-    safe_b = jnp.where(routed, bucket_idx, 0)
-    served = active & routed & rep["okb"][safe_b]
-    pages = jnp.where(served[:, None], rep["data"][safe_b], 0)
+    with jax.named_scope("exchange"):
+        dest = bits.addr_node(addrs)
+        bucket_idx, routed = transport.bucketize(dest, active, N, C)
+        out = transport.scatter_to_buckets(bits.addr_page(addrs),
+                                           bucket_idx, N * C)
+        inc = xch(out)
+    with jax.named_scope("owner_gather"):
+        if pallas_page.use_pallas(cfg):
+            data = pallas_page.gather_pages(pool, inc)
+        else:
+            data = pool[jnp.clip(inc, 0, P - 1)]
+    with jax.named_scope("exchange"):
+        rep = xch({"data": data, "okb": (inc >= 0) & (inc < P)})
+        safe_b = jnp.where(routed, bucket_idx, 0)
+        served = active & routed & rep["okb"][safe_b]
+        pages = jnp.where(served[:, None], rep["data"][safe_b], 0)
     return pages, served
 
 
@@ -526,6 +552,7 @@ class DSM(_HostOps):
 
         self.pool = _zeros((N * P, PAGE_WORDS), jnp.int32)
         self.locks = _zeros((N * L,), jnp.int32)
+        self._counter_box = {}
         self.counters = _zeros((N * N_COUNTERS,), jnp.uint32)
         # Out-of-line VALUE HEAP — the second DSM region (see
         # DSMConfig.heap_pages_per_node; models/value_heap.py owns the
@@ -609,13 +636,15 @@ class DSM(_HostOps):
         # collector on the process-wide registry — snapshots then carry
         # ``dsm.read_ops`` etc. without any per-op host cost (the
         # counters accumulate on device; reading them is the same
-        # materialization counter_snapshot always did).  Weakly bound:
-        # a dead DSM drops out instead of pinning its device arrays.
+        # materialization counter_snapshot always did).  Bound to the
+        # counters alone: a dead DSM does not pin its pool, and its last
+        # counters (N x N_COUNTERS words) stay readable, so a caller that
+        # has let its cluster go still reads the totals of its run.
+        box, mh = self._counter_box, self.multihost
+        obs.register_collector(
+            "dsm", lambda: _counter_totals(box["array"], mh))
         import weakref
         ref = weakref.ref(self)
-        obs.register_collector(
-            "dsm", lambda: (lambda d: d.counter_snapshot() if d is not None
-                            else {})(ref()))
         # HBM accountant (obs/device.py): the DSM's device-resident
         # arrays ARE the pool-side HBM footprint — register them as
         # weakref-bound byte sources so ``device.hbm_*`` gauges and the
@@ -959,29 +988,46 @@ class DSM(_HostOps):
 
     # -- observability (write_test.cpp:72-76 parity) -------------------------
 
+    @property
+    def counters(self):
+        """The device op counters, [N * N_COUNTERS] uint32 sharded over
+        nodes (engine steps donate and replace them)."""
+        return self._counter_box["array"]
+
+    @counters.setter
+    def counters(self, value):
+        self._counter_box["array"] = value
+
     def counter_snapshot(self) -> dict[str, int]:
         """Op counters summed over this process's nodes (single-process:
         the whole cluster).  Multi-host drivers aggregate across hosts
         with ``keeper.sum`` — the reference's pattern exactly
         (``dsm->sum``, test/benchmark.cpp:336-346)."""
-        if self.multihost:
-            c = np.concatenate([np.asarray(s.data)
-                                for s in self.counters.addressable_shards])
-        else:
-            c = np.asarray(self.counters)
-        c = c.reshape(-1, N_COUNTERS)
-        tot = c.sum(axis=0, dtype=np.uint64)
-        return {
-            "read_ops": int(tot[CNT_READ_OPS]),
-            "read_bytes": int(tot[CNT_READ_PAGES]) * CFG.PAGE_BYTES,
-            "write_ops": int(tot[CNT_WRITE_OPS]),
-            "write_bytes": int(tot[CNT_WRITE_WORDS]) * 4,
-            "cas_ops": int(tot[CNT_CAS_OPS]),
-            "faa_ops": int(tot[CNT_FAA_OPS]),
-            "write_word_ops": int(tot[CNT_WW_OPS]),
-            "combine_groups": int(tot[CNT_COMBINE_GROUPS]),
-            "combine_locks_saved": int(tot[CNT_COMBINE_SAVED]),
-        }
+        return _counter_totals(self.counters, self.multihost)
+
+
+def _counter_totals(counters, multihost: bool) -> dict[str, int]:
+    """:meth:`DSM.counter_snapshot` of a counters array."""
+    if multihost:
+        c = np.concatenate([np.asarray(s.data)
+                            for s in counters.addressable_shards])
+    else:
+        c = np.asarray(counters)
+    c = c.reshape(-1, N_COUNTERS)
+    tot = c.sum(axis=0, dtype=np.uint64)
+    return {
+        "read_ops": int(tot[CNT_READ_OPS]),
+        "read_bytes": int(tot[CNT_READ_PAGES]) * CFG.PAGE_BYTES,
+        "write_ops": int(tot[CNT_WRITE_OPS]),
+        "write_bytes": int(tot[CNT_WRITE_WORDS]) * 4,
+        "cas_ops": int(tot[CNT_CAS_OPS]),
+        "faa_ops": int(tot[CNT_FAA_OPS]),
+        "write_word_ops": int(tot[CNT_WW_OPS]),
+        "combine_groups": int(tot[CNT_COMBINE_GROUPS]),
+        "combine_locks_saved": int(tot[CNT_COMBINE_SAVED]),
+        "xchg_remote_rows": int(tot[CNT_XCHG_REMOTE]),
+        "xchg_overflow_rows": int(tot[CNT_XCHG_OVERFLOW]),
+    }
 
 
 class ReplicatedDSM(_HostOps):

@@ -596,19 +596,15 @@ def make_staged_step(eng, *, n_keys: int, theta: float, salt: int,
     def serve_fanout(pool, counters, ukhi, uklo, start, active, seg):
         """chained/fused serve body: routed descent + the monotone
         per-client answer fan-out (seg is NONDECREASING, so the gather
-        is sequential in HBM, unlike an inverse-permuted one).  GLOBAL
-        indices on multi-node meshes: the answer table all-gathers
-        tiled, node n's rows at [n*dev_b, (n+1)*dev_b)."""
+        is sequential in HBM, unlike an inverse-permuted one).  Each
+        node combines its own clients, so ``seg`` indexes the node's own
+        [dev_b, 4] answer table on any mesh size."""
         counters, done, found, vhi, vlo = search_routed_spmd(
             pool, counters, i32(ukhi), i32(uklo), root, active, start,
             cfg=cfg, iters=iters)
         with jax.named_scope("fanout"):
             ans = jnp.stack([found.astype(jnp.int32), vhi, vlo,
                              jnp.zeros_like(vhi)], axis=-1)  # [U_loc, 4]
-            if N > 1:
-                node = lax.axis_index(AXIS)
-                ans = transport.gather_rows(ans, AXIS)
-                seg = seg + node.astype(jnp.int32) * dev_b
             safe = jnp.clip(seg, 0, ans.shape[0] - 1)
             out = jnp.take_along_axis(ans, safe[:, None], axis=0)
         return counters, out[:, 0] != 0, out[:, 1], out[:, 2]
@@ -658,8 +654,10 @@ def make_staged_step(eng, *, n_keys: int, theta: float, salt: int,
         # the serve is the ENGINE's host-staged program object: same jit
         # cache entry, same donation, same HLO as the throughput phase
         # (already ledger-wrapped at the engine cache site — wrap() is
-        # idempotent, so the identity pin keeps holding)
-        jserve = eng._get_search_fanout(iters)
+        # idempotent, so the identity pin keeps holding).  Multi-node,
+        # its node-local variant: a client's unique row is on its own
+        # node, so no answer-table all-gather (``inv`` stays GLOBAL)
+        jserve = eng._get_search_fanout(iters, local=True)
 
         jcache = cache_tables = None
         R_resid = int(dev_b_resid) if dev_b_resid else dev_b
