@@ -5,9 +5,12 @@ Where the reference posts verbs on per-destination RC queue pairs
 fixed-capacity batch of requests per step with one ``all_to_all`` exchange:
 each node scatters its requests into per-destination buckets of capacity
 ``C``; one tiled all_to_all delivers every bucket to its owner; replies ride
-the reverse exchange.  Requests beyond a bucket's capacity are dropped with
-``ok=0`` and retried by the caller — the moral equivalent of a full RDMA
-send queue.
+the reverse exchange.  A request's slot is its rank among the requests bound
+for the same node, in request order, found by a linear scan and no search
+(:func:`bucketize`): a running count per node on small meshes, a stable sort
+and a running max over its run heads on large ones.  Requests beyond a
+bucket's capacity are dropped with ``ok=0`` and retried by the caller — the
+moral equivalent of a full RDMA send queue.
 
 All helpers run *inside* ``shard_map`` on per-node shards.
 """
@@ -38,8 +41,25 @@ def _tree_nbytes(tree) -> int:
     return sum(x.size * x.dtype.itemsize for x in jax.tree.leaves(tree))
 
 
+#: Up to this many nodes a request's rank is a running count over an
+#: [n_nodes, R] one-hot, O(n_nodes * R) with no sort; past it, a stable
+#: sort and one running max, O(R log R) whatever the node count.
+_ONE_HOT_NODES = 8
+
+
 def bucketize(dest, active, n_nodes: int, capacity: int):
     """Assign each request a slot in its destination bucket.
+
+    A request's slot is its rank among the active requests bound for
+    the same node, in request order.  Up to :data:`_ONE_HOT_NODES`
+    nodes the rank is the running count of the request's node over an
+    ``[n_nodes, R]`` one-hot (``cumsum`` along R, the minor axis), with
+    no sort.  Past that the requests are stably sorted by destination,
+    a request's rank is its sorted position minus where its run of equal
+    destinations starts, and one running max over the run heads
+    (``lax.cummax``) finds every run start.  Both are linear scans, not
+    a search, and give the same slots.  Requests ranked at or past
+    ``capacity`` are not routed.
 
     Args:
       dest: [R] int32 destination node per request.
@@ -51,14 +71,22 @@ def bucketize(dest, active, n_nodes: int, capacity: int):
        routed[R] bool).
     """
     R = dest.shape[0]
-    d = jnp.where(active, dest, n_nodes).astype(jnp.int32)
-    perm = jnp.argsort(d, stable=True)
-    sd = d[perm]
-    starts = jnp.searchsorted(sd, sd, side="left")
-    rank = jnp.arange(R, dtype=jnp.int32) - starts.astype(jnp.int32)
-    ok = (sd < n_nodes) & (rank < capacity)
-    bidx = jnp.where(ok, sd * capacity + rank, -1).astype(jnp.int32)
-    bucket_idx = jnp.zeros(R, jnp.int32).at[perm].set(bidx)
+    with jax.named_scope("bucketize"):
+        d = jnp.where(active, dest, n_nodes).astype(jnp.int32)
+        if n_nodes <= _ONE_HOT_NODES:
+            hot = d[None, :] == jnp.arange(n_nodes, dtype=jnp.int32)[:, None]
+            count = jnp.cumsum(hot, axis=1, dtype=jnp.int32)
+            rank = jnp.sum(jnp.where(hot, count, 0), axis=0) - 1
+            ok = (d < n_nodes) & (rank < capacity)
+            bucket_idx = jnp.where(ok, d * capacity + rank, -1)
+        else:
+            pos = jnp.arange(R, dtype=jnp.int32)
+            sd, perm = jax.lax.sort((d, pos), num_keys=1, is_stable=True)
+            head = (pos == 0) | (sd != jnp.roll(sd, 1))
+            rank = pos - jax.lax.cummax(jnp.where(head, pos, 0))
+            ok = (sd < n_nodes) & (rank < capacity)
+            bidx = jnp.where(ok, sd * capacity + rank, -1)
+            bucket_idx = jnp.zeros(R, jnp.int32).at[perm].set(bidx)
     return bucket_idx, bucket_idx >= 0
 
 
